@@ -1676,6 +1676,8 @@ def main() -> None:
                          "replica_locality,replica,recovery,kernels,"
                          "roofline")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     benches = {
         "online": bench_online, "offline": bench_offline,
         "ingest": bench_ingest, "ingest_graph": bench_ingest_graph,

@@ -21,6 +21,13 @@ records and every shard's intact WAL records, so whatever the kill tore
 off the end costs recovery depth, never correctness (``docs/
 ARCHITECTURE.md`` "Durability & recovery" has the argument).
 
+The parent process stays off the accelerator: recovery, the oracle and
+the audit are host-side NumPy (the audit is a byte comparison of the
+views' host CSR arrays, whose device copies are only made on first
+use), and the RPC client only decodes frames. On a TPU host the chip
+therefore belongs to whichever serving subprocess is running, never to
+this script.
+
     PYTHONPATH=src python examples/crash_recovery_demo.py
 """
 from __future__ import annotations
@@ -115,7 +122,7 @@ def main() -> None:
         oracle.seal_epoch(b.version.epoch)
         got = rec.join_view(b.version)
         want = oracle.join_view(b.version)
-        for field in ("offsets", "src", "dst"):
+        for field in ("np_offsets", "np_src", "np_dst"):
             assert np.array_equal(getattr(got, field),
                                   getattr(want, field)), \
                 f"epoch {b.version.epoch}: {field} diverged"

@@ -51,8 +51,10 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import functools
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -302,28 +304,56 @@ class _BatchDelta:
 class JoinView:
     """CSR of one snapshot: dst-grouped in-edges (the join view).
 
-    Rows are in canonical (dst, src) order. The trailing ``np_*`` fields are
-    host-side state for O(delta) incremental maintenance.
+    Rows are in canonical (dst, src) order. The ``np_*`` fields are the
+    host-side CSR (also the state for O(delta) incremental maintenance);
+    the device arrays (``offsets``, ``src``, ``dst``, ``out_degree``,
+    ``in_degree``) are uploaded on first use and kept. Views that are only
+    read on the host — per-shard views feeding the stitch and replica
+    routing, or an audit comparing CSRs — therefore never touch the
+    accelerator.
     """
     version: Version
     n: int
-    offsets: jnp.ndarray       # (n+1,)
-    src: jnp.ndarray           # (m,) source vertex per in-edge
-    dst: jnp.ndarray           # (m,)
-    out_degree: jnp.ndarray    # (n,)
-    in_degree: jnp.ndarray     # (n,)
-    np_keys: Optional[np.ndarray] = dataclasses.field(
-        default=None, repr=False)    # (m,) int64 (dst<<32)|src, ascending
-    np_src: Optional[np.ndarray] = dataclasses.field(default=None, repr=False)
-    np_dst: Optional[np.ndarray] = dataclasses.field(default=None, repr=False)
-    np_in_deg: Optional[np.ndarray] = dataclasses.field(
-        default=None, repr=False)    # (n,) int64
-    np_out_deg: Optional[np.ndarray] = dataclasses.field(
-        default=None, repr=False)    # (n,) int64
+    np_offsets: np.ndarray = dataclasses.field(repr=False)  # (n+1,) int64
+    np_keys: np.ndarray = dataclasses.field(
+        repr=False)                  # (m,) int64 (dst<<32)|src, ascending
+    np_src: np.ndarray = dataclasses.field(repr=False)      # (m,)
+    np_dst: np.ndarray = dataclasses.field(repr=False)      # (m,)
+    np_in_deg: np.ndarray = dataclasses.field(repr=False)   # (n,) int64
+    np_out_deg: np.ndarray = dataclasses.field(repr=False)  # (n,) int64
 
     @property
     def m(self) -> int:
-        return int(self.src.shape[0])
+        return int(self.np_src.shape[0])
+
+    @functools.cached_property
+    def offsets(self) -> jnp.ndarray:
+        return _upload(self.np_offsets)
+
+    @functools.cached_property
+    def src(self) -> jnp.ndarray:
+        """(m,) source vertex per in-edge, on the device."""
+        return _upload(self.np_src)
+
+    @functools.cached_property
+    def dst(self) -> jnp.ndarray:
+        return _upload(self.np_dst)
+
+    @functools.cached_property
+    def out_degree(self) -> jnp.ndarray:
+        return _upload(self.np_out_deg.astype(np.float32))
+
+    @functools.cached_property
+    def in_degree(self) -> jnp.ndarray:
+        return _upload(self.np_in_deg.astype(np.float32))
+
+
+def _upload(host: np.ndarray) -> jnp.ndarray:
+    # a first access may happen while a caller traces (e.g. inside a
+    # while_loop body); the cached value must be a concrete array, never a
+    # tracer that outlives its trace
+    with jax.ensure_compile_time_eval():
+        return jnp.asarray(host)
 
 
 def _edge_keys(src: np.ndarray, dst: np.ndarray) -> np.ndarray:
@@ -368,11 +398,8 @@ def build_join_view(version: Version, n: int, keys, src_s, dst_s,
     stitcher so all three produce byte-identical CSRs."""
     offsets = np.zeros(n + 1, np.int64)
     np.cumsum(in_deg, out=offsets[1:])
-    return JoinView(version, n, jnp.asarray(offsets),
-                    jnp.asarray(src_s), jnp.asarray(dst_s),
-                    jnp.asarray(out_deg.astype(np.float32)),
-                    jnp.asarray(in_deg.astype(np.float32)),
-                    np_keys=keys, np_src=src_s, np_dst=dst_s,
+    return JoinView(version, n, np_offsets=offsets, np_keys=keys,
+                    np_src=src_s, np_dst=dst_s,
                     np_in_deg=np.asarray(in_deg, np.int64),
                     np_out_deg=np.asarray(out_deg, np.int64))
 
@@ -590,9 +617,9 @@ class DynamicGraph:
                       use_kernel: bool = False) -> np.ndarray:
         """created <= v < deleted — the paper's snapshot rule on edges.
 
-        ``use_kernel`` routes the resolve through the Pallas
-        ``snapshot_resolve`` kernel (liveness as a 2-slot multi-version
-        resolve); the NumPy path is the portable host fallback. Stamps are
+        ``use_kernel`` evaluates the rule on the device with the Pallas
+        ``liveness_mask`` kernel; the NumPy path is the portable host
+        fallback. Stamps are
         int32 data-plane packed natively, so the kernel consumes the
         stored arrays directly — no 64→32-bit host conversion here.
         """
@@ -768,35 +795,39 @@ def _churn_batches(rng, n_epochs: int, sample_adds, *, delete_frac: float,
     ``(src, dst)`` adds from ``sample_adds(rng)``, live-set bookkeeping,
     ``delete_frac`` uniform deletes and ``readd_frac`` re-adds of
     previously deleted edges. One implementation of the delete/re-add
-    bookkeeping keeps the uniform and skewed generators in lockstep."""
-    live: list[tuple[int, int]] = []
-    dead: list[tuple[int, int]] = []
+    bookkeeping keeps the uniform and skewed generators in lockstep.
+
+    The live and dead edge lists are int32 arrays in arrival order, so a
+    stream of tens of millions of edges costs array passes, not Python
+    objects per edge."""
+    empty = np.zeros(0, np.int32)
+    live_s, live_d = empty, empty
+    dead_s, dead_d = empty, empty
     batches = []
     for e in range(n_epochs):
         src, dst = sample_adds(rng)
-        adds_s, adds_d = list(src), list(dst)
-        if readd_frac and dead:
-            k = int(len(dead) * readd_frac)
-            for i in rng.choice(len(dead), size=k, replace=False):
-                s, d = dead[i]
-                adds_s.append(s)
-                adds_d.append(d)
-        n_del = int(len(live) * delete_frac)
+        adds_s = np.asarray(src, np.int32)
+        adds_d = np.asarray(dst, np.int32)
+        if readd_frac and len(dead_s):
+            k = int(len(dead_s) * readd_frac)
+            pick = rng.choice(len(dead_s), size=k, replace=False)
+            adds_s = np.concatenate([adds_s, dead_s[pick]])
+            adds_d = np.concatenate([adds_d, dead_d[pick]])
+        n_del = int(len(live_s) * delete_frac)
         if n_del:
-            idx = rng.choice(len(live), size=n_del, replace=False)
-            sel = set(idx.tolist())
-            dels = [live[i] for i in idx]
-            live = [x for i, x in enumerate(live) if i not in sel]
-            dead.extend(dels)
-            del_s = np.array([x[0] for x in dels], np.int32)
-            del_d = np.array([x[1] for x in dels], np.int32)
+            idx = rng.choice(len(live_s), size=n_del, replace=False)
+            del_s, del_d = live_s[idx], live_d[idx]
+            keep = np.ones(len(live_s), bool)
+            keep[idx] = False
+            live_s, live_d = live_s[keep], live_d[keep]
+            dead_s = np.concatenate([dead_s, del_s])
+            dead_d = np.concatenate([dead_d, del_d])
         else:
-            del_s = del_d = np.zeros(0, np.int32)
-        live.extend(zip(adds_s, adds_d, strict=True))
+            del_s = del_d = empty
+        live_s = np.concatenate([live_s, adds_s])
+        live_d = np.concatenate([live_d, adds_d])
         batches.append(MutationBatch(
-            Version(e, 0),
-            add_src=np.array(adds_s, np.int32),
-            add_dst=np.array(adds_d, np.int32),
+            Version(e, 0), add_src=adds_s, add_dst=adds_d,
             del_src=del_s, del_dst=del_d))
     return batches
 

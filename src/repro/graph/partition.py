@@ -26,7 +26,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.graph.dyngraph import JoinView
@@ -206,7 +205,7 @@ def distributed_join_group_by(pg: PartitionedGraph, values: jnp.ndarray,
     else:
         raise ValueError(mode)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P("data"), P("data"), P("data"), P("data")),
         out_specs=P("data"))

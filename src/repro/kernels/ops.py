@@ -1,8 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On the CPU container the kernels execute in interpret mode (the kernel body
+On the CPU backend the kernels execute in interpret mode (the kernel body
 runs in Python op-by-op — bit-accurate control flow, no Mosaic); on TPU they
-compile natively. ``repro.nn``/``repro.graph`` call through this module so
+compile natively. Any other backend is an error rather than a silent
+interpreter run. ``repro.nn``/``repro.graph`` call through this module so
 the switch is one place.
 """
 from __future__ import annotations
@@ -16,10 +17,20 @@ from repro.kernels import snapshot_resolve as _sr
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels compile for TPU and interpret on CPU only; "
+            f"the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 def segment_sum(values, segment_ids, num_segments, **kw):
+    if num_segments > _ss.MAX_SEGMENTS:
+        raise ValueError(
+            f"segment_sum keeps all {num_segments} segments resident in "
+            f"VMEM; the kernel compiles for at most "
+            f"MAX_SEGMENTS={_ss.MAX_SEGMENTS} (kernels/segment_sum.py)")
     kw.setdefault("interpret", _interpret())
     return _ss.segment_sum(values, segment_ids, num_segments, **kw)
 

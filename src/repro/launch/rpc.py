@@ -50,7 +50,9 @@ The dispatcher never dies with a failed window: the scheduler's
 all-or-nothing contract re-queues undelivered requests, and the
 dispatcher retries after a short pause — e.g. queries that race ahead of
 the first global seal simply wait (their deadline, if any, still
-applies).
+applies). Any other failure is logged and counted in
+``ServerStats.dispatch_errors``, and the retry pause backs off while the
+failures repeat.
 """
 from __future__ import annotations
 
@@ -70,7 +72,7 @@ from repro.core.versioned import Version
 from repro.graph.query import (ERR_BAD_QUERY, ERR_OVERLOADED, DegreeTopK,
                                KHop, PageRankQuery, Query, QueryRequest,
                                QueryResponse, Reachability)
-from repro.launch.serve_graph import GraphQueryServer
+from repro.launch.serve_graph import GraphQueryServer, NothingSealedError
 
 _LEN = struct.Struct(">I")
 MAX_FRAME = 64 * 1024 * 1024    # refuse absurd frames instead of OOMing
@@ -300,19 +302,27 @@ class GraphRPCServer:
         flavor preserves the PR 8 behavior for the benchmark baseline."""
         if work is None:
             work = self.server.work_available
+        failures = 0
         while not self._stop.is_set():
             if not work.wait(timeout=0.2):
                 continue
             if self.batch_wait_s:
                 time.sleep(self.batch_wait_s)   # let a batch accumulate
             work.clear()
+            # all-or-nothing window: on failure everything undelivered
+            # was re-queued, so the retry below loses nothing
             try:
                 self.server.run_window(lane)
+                failures = 0
+                continue
+            except NothingSealedError:
+                pause = 0.005
             except Exception:
-                # all-or-nothing window: everything undelivered was
-                # re-queued (e.g. nothing sealed yet) — retry shortly
-                time.sleep(0.005)
-                work.set()
+                self.server.note_dispatch_error()
+                failures += 1
+                pause = min(1.0, 0.005 * 2 ** failures)
+            time.sleep(pause)
+            work.set()
 
     def _serve_conn(self, conn: socket.socket) -> None:
         send_lock = threading.Lock()   # per-connection: frames atomic

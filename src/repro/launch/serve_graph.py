@@ -50,6 +50,7 @@ import argparse
 import collections
 import dataclasses
 import itertools
+import logging
 import threading
 import time
 from typing import Callable, Iterable, Mapping, Optional, Sequence
@@ -69,6 +70,9 @@ from repro.graph.query import (ERR_BAD_PIN, ERR_BAD_QUERY, ERR_DEADLINE,
                                query_touch_vertices)
 from repro.graph.sharded import ShardedDynamicGraph
 from repro.graph.wal import ShardFaultError
+from repro.launch.compile_cache import enable_compile_cache
+
+log = logging.getLogger(__name__)
 
 QUERY_KINDS = ("k_hop", "reachability", "degree_topk", "pagerank")
 
@@ -113,7 +117,13 @@ class ServerStats:
     the last *published* sealed snapshot, never a partial one;
     ``stale_epochs`` is how many ingested epochs the serving frontier
     lags; ``seal_failures`` counts failed seal attempts over the
-    server's lifetime (it never resets on recovery)."""
+    server's lifetime (it never resets on recovery).
+
+    Swallowed-failure telemetry: ``prewarm_errors`` counts publish-time
+    trace prewarms that raised, ``dispatch_errors`` counts RPC dispatcher
+    windows that failed for any reason other than "nothing sealed yet".
+    Both are logged with their traceback; a healthy server keeps both
+    at 0."""
     served: int
     windows: int
     queue_depth: int
@@ -155,6 +165,14 @@ class ServerStats:
     degraded: bool = False
     stale_epochs: int = 0
     seal_failures: int = 0
+    prewarm_errors: int = 0
+    dispatch_errors: int = 0
+
+
+class NothingSealedError(RuntimeError):
+    """A window with unpinned requests ran before any epoch was sealed
+    on every shard; its requests were re-queued, so retrying later is the
+    whole remedy."""
 
 
 @dataclasses.dataclass
@@ -331,6 +349,8 @@ class GraphQueryServer:
         self._prewarm_stop = threading.Event()
         self._prewarm_thread: Optional[threading.Thread] = None
         self.prewarm_runs = 0
+        self.prewarm_errors = 0          # under _prewarm_lock
+        self.dispatch_errors = 0         # under _serve_lock
         graph.on_frontier_advance(self._on_seal)
 
     # -- ingestion side ----------------------------------------------------
@@ -403,7 +423,8 @@ class GraphQueryServer:
         routed buckets when the snapshot ships a replica plan) against
         each newly published view, so the first query after a seal pays a
         dict lookup instead of a retrace. Best-effort by design — a
-        prewarm failure must never take serving down with it."""
+        prewarm failure must never take serving down with it — but never
+        silent: each one is logged and counted in ``prewarm_errors``."""
         while not self._prewarm_stop.is_set():
             self._prewarm_wake.wait()
             if self._prewarm_stop.is_set():
@@ -417,6 +438,9 @@ class GraphQueryServer:
             try:
                 self.engine.warm_traces(view, routed)
             except Exception:
+                log.exception("trace prewarm failed at version %s", v)
+                with self._prewarm_lock:
+                    self.prewarm_errors += 1
                 continue
             with self._prewarm_lock:
                 self.prewarm_runs += 1
@@ -431,6 +455,14 @@ class GraphQueryServer:
         t = self._prewarm_thread
         if t is not None:
             t.join(timeout=5.0)
+
+    def note_dispatch_error(self) -> None:
+        """Log (with the traceback being handled) and count one failed
+        dispatcher window; the RPC tier calls this from its ``except``
+        block, where the window's requests are already re-queued."""
+        log.exception("query window failed; its requests were re-queued")
+        with self._serve_lock:
+            self.dispatch_errors += 1
 
     def _drain_touches(self) -> None:
         """Move buffered query touches from the read plane into the
@@ -652,8 +684,9 @@ class GraphQueryServer:
 
         Legacy-compatible failure semantics: if nothing is globally
         sealed yet, the undeliverable entries are re-queued AHEAD of
-        later submissions (each on its own lane) and ``RuntimeError``
-        raises; if the engine fails mid-window, every live entry is
+        later submissions (each on its own lane) and
+        :class:`NothingSealedError` (a ``RuntimeError``) raises; if the
+        engine fails mid-window, every live entry is
         re-queued un-answered and the error propagates — a window is
         delivered all-or-nothing.
 
@@ -722,7 +755,7 @@ class GraphQueryServer:
                     e for e in live if e.lane != "cheap"]
                 self.shed_deadline += len(expired)
             self._deliver(expired)
-            raise RuntimeError(
+            raise NothingSealedError(
                 "no globally sealed snapshot yet — seal an epoch on "
                 "every shard before querying")
         # group by effective snapshot so one engine call per (version,
@@ -915,6 +948,7 @@ class GraphQueryServer:
         rcache = self.engine.result_cache_stats()
         with self._prewarm_lock:
             prewarm_runs = self.prewarm_runs
+            prewarm_errors = self.prewarm_errors
         with self._serve_lock:
             lat = np.asarray(self.latencies_s)
             p50, p95, p99 = _quantiles(lat)
@@ -972,7 +1006,9 @@ class GraphQueryServer:
                 prewarm_runs=prewarm_runs,
                 degraded=degraded,
                 stale_epochs=stale_epochs,
-                seal_failures=seal_failures)
+                seal_failures=seal_failures,
+                prewarm_errors=prewarm_errors,
+                dispatch_errors=self.dispatch_errors)
         return stats
 
 
@@ -1018,6 +1054,7 @@ def main():
                     help="graph checkpoint cadence in sealed epochs "
                          "(with --wal-dir)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     batches = synthesize_churn_stream(args.vertices, args.epochs,
                                       args.adds_per_epoch, seed=args.seed,

@@ -35,6 +35,15 @@ def test_segment_sum_empty_segments():
     assert np.asarray(got)[6].sum() == 0
 
 
+def test_segment_sum_keeps_every_f32_bit():
+    # the MXU multiplies bf16; the kernel's three bf16 pieces must carry a
+    # lone f32 value through bit for bit
+    vals = jnp.array([[1.2345678e-5], [-3.3333333], [7e-30], [123456.79]],
+                     jnp.float32)
+    got = segment_sum(vals, jnp.arange(4), 4, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(vals))
+
+
 # ------------------------------------------------------------ flash_attention
 @pytest.mark.parametrize("B,Hq,Hkv,S,hd", [
     (1, 2, 2, 128, 32), (2, 4, 2, 256, 64), (1, 8, 1, 128, 16),

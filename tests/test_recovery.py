@@ -641,3 +641,45 @@ def test_chaos_faults_wal_and_recovery_match_oracle(tmp_path):
     inj.heal()
     rec = ShardedDynamicGraph.recover(tmp_path)
     _assert_equiv(rec, oracle, batches)
+
+
+_OFF_DEVICE_AUDIT = """
+import sys
+import numpy as np
+from jax._src import xla_bridge
+from repro.graph.dyngraph import synthesize_churn_stream
+from repro.graph.sharded import ShardedDynamicGraph
+wal_dir = sys.argv[1]
+batches = synthesize_churn_stream(300, 5, 200, seed=1, delete_frac=0.2)
+e_max = sum(len(b.add_src) for b in batches) + 16
+live = ShardedDynamicGraph(2, 300, e_max, wal_dir=wal_dir)
+for b in batches:
+    live.ingest(b)
+    live.seal_epoch(b.version.epoch)
+for w in [*live.wal_shards, live.wal]:
+    w.sync()
+rec = ShardedDynamicGraph.recover(wal_dir)
+oracle = ShardedDynamicGraph(2, 300, e_max)
+for b in batches:
+    oracle.ingest(b)
+    oracle.seal_epoch(b.version.epoch)
+    got, want = rec.join_view(b.version), oracle.join_view(b.version)
+    for field in ("np_offsets", "np_src", "np_dst"):
+        assert np.array_equal(getattr(got, field), getattr(want, field))
+assert not xla_bridge._backends, sorted(xla_bridge._backends)
+"""
+
+
+def test_recovery_audit_never_touches_the_accelerator(tmp_path):
+    # examples/crash_recovery_demo.py recovers and audits in the parent
+    # while a serving child owns the chip: ingest, WAL, recovery and the
+    # host-CSR audit must never initialize a JAX backend (fresh process,
+    # since this one already has)
+    import subprocess
+    import sys
+    env = dict(os.environ, PYTHONPATH=str(pathlib.Path(__file__)
+                                          .resolve().parents[1] / "src"))
+    out = subprocess.run([sys.executable, "-c", _OFF_DEVICE_AUDIT,
+                          str(tmp_path)], env=env, capture_output=True,
+                         text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
