@@ -8,7 +8,8 @@ Mechanizes the locking contracts written in prose in
   the declarative :data:`SPEC` registry for the classes whose contracts
   are part of the architecture (``GraphQueryServer._ingest_lock`` /
   ``GraphQueryServer._serve_lock`` — the serving tier's seal-swap planes —
-  ``GraphRPCServer._conn_lock``, ``SnapshotQueryEngine._rank_lock``), and
+  ``GraphRPCServer._conn_lock``, ``SnapshotQueryEngine._rank_lock``,
+  ``Spans._lock``), and
   inference for everything else —
   any attribute *written* under ``with self.<lock>`` somewhere in a class
   is treated as guarded by that lock everywhere in the class.
@@ -77,6 +78,7 @@ SPEC: dict[str, ClassLockSpec] = {
             "_pending_cheap", "_pending_expensive", "_serving",
             "_published", "_touch_buffer", "_touch_buffered",
             "served", "windows", "shed_overload", "shed_deadline",
+            "queue_wait_s",
             "latencies_s", "_kind_latencies", "_lane_latencies",
         }),
         # prewarm mailbox: the one-slot coalescing target the publish
@@ -84,6 +86,11 @@ SPEC: dict[str, ClassLockSpec] = {
         "_prewarm_lock": frozenset({
             "_prewarm_target", "prewarm_runs",
         }),
+    }),
+    # the span accumulator every serving stage writes to: a leaf lock
+    # (nothing is acquired while it is held) over its three tallies
+    "Spans": ClassLockSpec(locks={
+        "_lock": frozenset({"_seconds", "_counts", "_counters"}),
     }),
     # the RPC listener's only shared mutable state is the live-connection
     # set (reader threads add/remove themselves; stop() snapshots it) —

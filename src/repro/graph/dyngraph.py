@@ -339,6 +339,18 @@ class JoinView:
     def dst(self) -> jnp.ndarray:
         return _upload(self.np_dst)
 
+    @property
+    def edges_on_device(self) -> bool:
+        """True once ``src`` and ``dst`` have been uploaded."""
+        return "src" in self.__dict__ and "dst" in self.__dict__
+
+    def upload_edges(self) -> int:
+        """Upload ``src`` and ``dst`` now, where not yet on the device,
+        and wait for the copies; returns the host bytes uploaded."""
+        fresh = [name for name in ("src", "dst") if name not in self.__dict__]
+        jax.block_until_ready([getattr(self, name) for name in fresh])
+        return sum(getattr(self, f"np_{name}").nbytes for name in fresh)
+
     @functools.cached_property
     def out_degree(self) -> jnp.ndarray:
         return _upload(self.np_out_deg.astype(np.float32))

@@ -49,8 +49,10 @@ import dataclasses
 import threading
 from typing import Optional, Sequence, Union
 
+import jax
 import numpy as np
 
+from repro.core.spans import Spans, span
 from repro.core.versioned import Version
 from repro.graph import compute as gc
 from repro.graph.dyngraph import JoinView, prune_retired, prune_views
@@ -238,10 +240,11 @@ def query_touch_vertices(queries: Sequence[Query]) -> np.ndarray:
 class _SubView:
     """Edge-restricted stand-in for a :class:`JoinView`: exactly the
     surface the batched frontier kernels read (``n``/``m``/``src``/
-    ``dst``), holding the routed edge subset instead of the global CSR."""
+    ``dst``), holding the routed edge subset instead of the global CSR,
+    on the device."""
     n: int
-    src: np.ndarray
-    dst: np.ndarray
+    src: jax.Array
+    dst: jax.Array
 
     @property
     def m(self) -> int:
@@ -295,11 +298,17 @@ class SnapshotQueryEngine:
     past it, new results are served but not memoized (counted in
     ``result_cache_evictions``), so one version of a high-cardinality
     query stream cannot pin unbounded memory.
+
+    ``spans`` is the accumulator the engine's spans (``engine.route``,
+    ``engine.pad``, ``engine.upload``, ``engine.fetch``) and its
+    ``upload_bytes`` counter add to; the serving layer passes its own.
     """
 
     def __init__(self, *, result_cache: bool = True,
-                 result_cache_entries: int = 4096, **pagerank_kw):
+                 result_cache_entries: int = 4096,
+                 spans: Optional[Spans] = None, **pagerank_kw):
         self.pagerank_kw = pagerank_kw
+        self.spans = spans if spans is not None else Spans()
         self.result_cache = result_cache
         self.result_cache_entries = result_cache_entries
         self._rank_cache: dict[int, gc.PageRankResult] = {}
@@ -446,8 +455,11 @@ class SnapshotQueryEngine:
         be answered from these mirrors."""
         if routed is None or routed.plan.version.pack() != view.version.pack():
             return None
-        sub_src, sub_dst, fanout, hits, misses = replica_route(
-            routed.plan, routed.shard_views, anchors, hops)
+        with span(self.spans, "engine.route", hops=hops,
+                  anchors=int(anchors.size)) as s:
+            sub_src, sub_dst, fanout, hits, misses = replica_route(
+                routed.plan, routed.shard_views, anchors, hops)
+            s.note(fanout=fanout, rows=int(sub_src.size))
         # pow2-pad the routed subset on the host, with the kernels' own
         # phantom-row convention (src 0 gathers harmlessly, dst ``n`` is
         # the sliced-off segment). Routed edge counts vary per window —
@@ -456,12 +468,19 @@ class SnapshotQueryEngine:
         # routed windows onto a few stable shapes, so the replica path
         # keeps its traces warm even while the global CSR drifts
         width = gc.pad_pow2(sub_src.size)
-        if width > sub_src.size:
-            extra = width - sub_src.size
-            sub_src = np.concatenate(
-                [sub_src, np.zeros(extra, sub_src.dtype)])
-            sub_dst = np.concatenate(
-                [sub_dst, np.full(extra, view.n, sub_dst.dtype)])
+        with span(self.spans, "engine.pad", rows=width):
+            if width > sub_src.size:
+                extra = width - sub_src.size
+                sub_src = np.concatenate(
+                    [sub_src, np.zeros(extra, sub_src.dtype)])
+                sub_dst = np.concatenate(
+                    [sub_dst, np.full(extra, view.n, sub_dst.dtype)])
+        # the upload the kernels would otherwise make implicitly, here
+        # so that it is timed and counted: the same two copies
+        with span(self.spans, "engine.upload", rows=width):
+            dev_src, dev_dst = jax.block_until_ready(
+                jax.device_put((sub_src, sub_dst)))
+        self.spans.count("upload_bytes", sub_src.nbytes + sub_dst.nbytes)
         if record:
             # prewarm passes record=False: a trace-warming sweep must not
             # pollute the mirror-hit / fan-out telemetry real windows feed
@@ -471,7 +490,17 @@ class SnapshotQueryEngine:
                 self.routed_windows += 1
                 self.fanout_hist[fanout] = \
                     self.fanout_hist.get(fanout, 0) + 1
-        return _SubView(view.n, sub_src, sub_dst)
+        return _SubView(view.n, dev_src, dev_dst)
+
+    def _resident(self, view: JoinView) -> JoinView:
+        """``view`` with its edge arrays on the device: a view uploads
+        them once, on first use, and that upload is timed and counted
+        here (``engine.upload``, ``upload_bytes``)."""
+        if not view.edges_on_device:
+            with span(self.spans, "engine.upload", rows=view.m):
+                nbytes = view.upload_edges()
+            self.spans.count("upload_bytes", nbytes)
+        return view
 
     # -- window execution --------------------------------------------------
     def execute(self, view: JoinView, queries: Sequence[Query], *,
@@ -592,7 +621,7 @@ class SnapshotQueryEngine:
                 and routed.plan.n_mirrored:
             hot = np.flatnonzero(routed.plan.mirrored)[:max_anchors] \
                 .astype(np.int32)
-        m = int(view.src.size)
+        m = view.m
         warmed = 0
 
         def fresh(key):
@@ -609,7 +638,7 @@ class SnapshotQueryEngine:
                 _, k, width = sig
                 anchors = np.zeros(width, np.int32)
                 if fresh((sig, m)):
-                    gc.batched_k_hop(view, anchors, k)
+                    gc.batched_k_hop(self._resident(view), anchors, k)
                     warmed += 1
                 if hot is not None:
                     sub = self._route(routed, view, hot, k, record=False)
@@ -622,7 +651,8 @@ class SnapshotQueryEngine:
                 # src == dst, so the while_loop exits on round one: the
                 # warm is the trace, not a graph sweep
                 if fresh((sig, m)):
-                    gc.batched_reachability(view, anchors, anchors, 1)
+                    gc.batched_reachability(self._resident(view), anchors,
+                                            anchors, 1)
                     warmed += 1
                 if hot is not None:
                     sub = self._route(routed, view, hot, 1, record=False)
@@ -663,8 +693,11 @@ class SnapshotQueryEngine:
 
         for k, idxs in khops.items():
             sources = np.asarray([queries[i].source for i in idxs], np.int32)
-            target = self._route(routed, view, sources, k) or view
-            reach = np.asarray(gc.batched_k_hop(target, sources, k))
+            target = (self._route(routed, view, sources, k)
+                      or self._resident(view))
+            reach = gc.batched_k_hop(target, sources, k)
+            with span(self.spans, "engine.fetch", queries=len(idxs)):
+                reach = np.asarray(reach)
             with self._rank_lock:
                 self.vectorized_calls["k_hop"] += 1
             for row, i in enumerate(idxs):
@@ -675,9 +708,11 @@ class SnapshotQueryEngine:
             dsts = np.asarray([queries[i].dst for i in idxs], np.int32)
             # frontier expansion only ever walks forward from the
             # sources, so they alone anchor the route
-            target = self._route(routed, view, srcs, max_hops) or view
-            got = np.asarray(gc.batched_reachability(target, srcs, dsts,
-                                                     max_hops))
+            target = (self._route(routed, view, srcs, max_hops)
+                      or self._resident(view))
+            got = gc.batched_reachability(target, srcs, dsts, max_hops)
+            with span(self.spans, "engine.fetch", queries=len(idxs)):
+                got = np.asarray(got)
             with self._rank_lock:
                 self.vectorized_calls["reachability"] += 1
             for row, i in enumerate(idxs):

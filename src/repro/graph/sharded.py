@@ -71,6 +71,7 @@ import numpy as np
 
 from repro.core.replica import ShardPlanner
 from repro.core.snapshotter import DataNode, IngestNode, SnapshotCoordinator
+from repro.core.spans import Spans, span
 from repro.core.versioned import (Version, pack32_checked, pack32_clamped,
                                   unpack32)
 from repro.graph.dyngraph import (DEFAULT_CHURN_THRESHOLD, MAXV, DynamicGraph,
@@ -795,8 +796,12 @@ class ShardedDynamicGraph:
         # nothing, so they seal empty epochs from the cutover on
         self.retired: set[int] = set()
         # per-shard cumulative apply seconds — the benchmark's critical-path
-        # model of parallel shard ingestion reads these
+        # model of parallel shard ingestion reads these; they are also the
+        # ``store.apply`` span's time (ServerStats exports their sum)
         self.shard_apply_seconds = [0.0] * n_shards
+        # the store's spans (store.ingest, store.seal, the WAL's), shared
+        # with the query server that serves this store
+        self.spans = Spans()
         # -- durability plane (graph/wal.py) -------------------------------
         self.fault_injector = fault_injector
         self.wal: Optional[GraphWal] = None
@@ -819,7 +824,7 @@ class ShardedDynamicGraph:
                     "route cannot be serialized for recovery)")
             self._attach_wal(
                 GraphWal(wal_dir, fsync=wal_fsync,
-                         fsync_every=wal_fsync_every),
+                         fsync_every=wal_fsync_every, spans=self.spans),
                 checkpoint_keep=checkpoint_keep, fresh=True)
 
     @property
@@ -845,6 +850,12 @@ class ShardedDynamicGraph:
             if inj is not None and not self._wal_replaying:
                 inj.check(shard_id, epoch)
             t0 = time.perf_counter()
+            # the span's time is kept by shard_apply_seconds alone
+            with span(None, "store.apply", shard=shard_id, epoch=epoch):
+                apply_epoch(epoch, payloads)
+            self.shard_apply_seconds[shard_id] += time.perf_counter() - t0
+
+        def apply_epoch(epoch: int, payloads: list) -> None:
             shard = self.shards[shard_id]
             # payloads arrive in three shapes: whole MutationBatches (the
             # single-shard passthrough), deferred _ShardSlices (the
@@ -904,7 +915,6 @@ class ShardedDynamicGraph:
                     rows = np.concatenate(
                         [encode_payload_rows(b) for b in batches])
                 w.append(epoch, rows)
-            self.shard_apply_seconds[shard_id] += time.perf_counter() - t0
         return on_seal
 
     # -- ingestion ---------------------------------------------------------
@@ -922,6 +932,12 @@ class ShardedDynamicGraph:
                 malformed batch (rejected before any version bookkeeping,
                 so the corrected batch can retry at the same version).
         """
+        with span(self.spans, "store.ingest", epoch=batch.version.epoch,
+                  rows=batch.size):
+            return self._dispatch(batch)
+
+    def _dispatch(self, batch: MutationBatch) -> int:
+        """:meth:`ingest`'s checks and dispatch, inside its span."""
         v = batch.version.pack()
         if v <= self._last_version:
             raise ValueError("mutation batches must have increasing versions")
@@ -1056,22 +1072,23 @@ class ShardedDynamicGraph:
         keeps the epoch invisible to queries, so the epoch aborts
         atomically from the store's point of view.
         """
-        while any(n.local_frontier < epoch for n in self.nodes):
+        with span(self.spans, "store.seal", epoch=epoch):
+            while any(n.local_frontier < epoch for n in self.nodes):
+                self.ingest_node.retry_blocked_batches()
+                lagging = [n for n in self.nodes if n.local_frontier < epoch]
+                if self.parallel_apply > 1 and len(lagging) > 1:
+                    futures = [self._executor().submit(
+                        n.seal_epoch, n.local_frontier + 1) for n in lagging]
+                    errors = [f.exception() for f in futures]   # barrier
+                    for err in errors:
+                        if err is not None:
+                            raise err
+                else:
+                    for node in lagging:
+                        node.seal_epoch(node.local_frontier + 1)
             self.ingest_node.retry_blocked_batches()
-            lagging = [n for n in self.nodes if n.local_frontier < epoch]
-            if self.parallel_apply > 1 and len(lagging) > 1:
-                futures = [self._executor().submit(
-                    n.seal_epoch, n.local_frontier + 1) for n in lagging]
-                errors = [f.exception() for f in futures]   # barrier
-                for err in errors:
-                    if err is not None:
-                        raise err
-            else:
-                for node in lagging:
-                    node.seal_epoch(node.local_frontier + 1)
-        self.ingest_node.retry_blocked_batches()
-        frontier = self.coordinator.advance()
-        self._trim_ingest_log()
+            frontier = self.coordinator.advance()
+            self._trim_ingest_log()
         return frontier
 
     def seal_shard(self, shard_id: int, epoch: int) -> int:
@@ -1376,7 +1393,8 @@ class ShardedDynamicGraph:
                             if wal_fsync is None else wal_fsync),
                      fsync_every=(int(meta.get("fsync_every", 32))
                                   if wal_fsync_every is None
-                                  else int(wal_fsync_every))),
+                                  else int(wal_fsync_every)),
+                     spans=store.spans),
             checkpoint_keep=ckpt_keep, fresh=False)
         store._wal_committed = durable
         store.fault_injector = fault_injector

@@ -75,6 +75,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro.core.spans import Spans, span
 from repro.core.versioned import Version
 from repro.train.checkpoint import CheckpointManager
 
@@ -177,9 +178,10 @@ def scan_segment(path, *, tail_ok: bool = True
     return records, off
 
 
-def _fsync_file(f) -> None:
-    f.flush()
-    os.fsync(f.fileno())
+def _fsync_file(f, spans: Optional[Spans]) -> None:
+    with span(spans, "wal.fsync"):
+        f.flush()
+        os.fsync(f.fileno())
 
 
 class ShardWal:
@@ -190,10 +192,12 @@ class ShardWal:
     list so the seal closure (which may run on the parallel apply plane)
     only ever touches its own writer; no lock is needed (reprolint's
     seal-plane rules treat the list like the other shard-owned state).
+    ``spans`` takes the ``wal.append`` and ``wal.fsync`` spans and the
+    ``wal_bytes`` counter (the store's accumulator).
     """
 
     def __init__(self, directory, shard_id: int, *, fsync: str = "batch",
-                 fsync_every: int = 32):
+                 fsync_every: int = 32, spans: Optional[Spans] = None):
         if fsync not in ("always", "batch", "never"):
             raise ValueError(f"unknown fsync policy {fsync!r}")
         self.dir = pathlib.Path(directory)
@@ -201,6 +205,7 @@ class ShardWal:
         self.shard_id = shard_id
         self.fsync = fsync
         self.fsync_every = int(fsync_every)
+        self.spans = spans
         self._f = None
         self._path: Optional[pathlib.Path] = None
         self._since_sync = 0
@@ -217,31 +222,36 @@ class ShardWal:
         buffer — this is the ingest hot path the < 15% overhead gate
         measures, and the intermediate ``tobytes``/concat copies were a
         third of its cost."""
-        if self._f is None:
-            self._open(epoch)
-        packed = Version(epoch, 0).pack()
-        arr = np.ascontiguousarray(rows, dtype="<i4")
-        body = memoryview(arr).cast("B") if arr.size else b""
-        crc = zlib.crc32(body, zlib.crc32(_PACKED.pack(packed)))
-        self._f.write(_HDR.pack(len(body), crc, packed))
-        self._f.write(body)
+        with span(self.spans, "wal.append", shard=self.shard_id,
+                  epoch=epoch, rows=len(rows)):
+            if self._f is None:
+                self._open(epoch)
+            packed = Version(epoch, 0).pack()
+            arr = np.ascontiguousarray(rows, dtype="<i4")
+            body = memoryview(arr).cast("B") if arr.size else b""
+            crc = zlib.crc32(body, zlib.crc32(_PACKED.pack(packed)))
+            self._f.write(_HDR.pack(len(body), crc, packed))
+            self._f.write(body)
+        if self.spans is not None:
+            self.spans.count("wal_bytes", _HDR.size + len(body))
+        # the fsync is a span of its own, outside wal.append
         if self.fsync == "always":
-            _fsync_file(self._f)
+            _fsync_file(self._f, self.spans)
         elif self.fsync == "batch":
             self._since_sync += 1
             if self._since_sync >= self.fsync_every:
-                _fsync_file(self._f)
+                _fsync_file(self._f, self.spans)
                 self._since_sync = 0
 
     def sync(self) -> None:
         if self._f is not None and self.fsync != "never":
-            _fsync_file(self._f)
+            _fsync_file(self._f, self.spans)
             self._since_sync = 0
 
     def close(self) -> None:
         if self._f is not None:
             if self.fsync != "never":
-                _fsync_file(self._f)
+                _fsync_file(self._f, self.spans)
             self._f.close()
             self._f = None
 
@@ -326,17 +336,20 @@ class GraphWal:
     shared with the shard segments. ``_lock`` is the WAL writer lock
     guarding the control-file handle and its fsync batcher (the store's
     serial thread is the only caller today; the lock pins the discipline
-    for the multi-host plane the ROADMAP sketches).
+    for the multi-host plane the ROADMAP sketches). ``spans`` (the
+    store's accumulator) takes the control log's ``wal.fsync`` spans and
+    is handed to every shard writer.
     """
 
     def __init__(self, directory, *, fsync: str = "batch",
-                 fsync_every: int = 32):
+                 fsync_every: int = 32, spans: Optional[Spans] = None):
         if fsync not in ("always", "batch", "never"):
             raise ValueError(f"unknown fsync policy {fsync!r}")
         self.dir = pathlib.Path(directory)
         self.dir.mkdir(parents=True, exist_ok=True)
         self.fsync = fsync
         self.fsync_every = int(fsync_every)
+        self.spans = spans
         self._lock = threading.Lock()
         self._control_f = open(self.control_path(self.dir), "ab")
         self._control_synced = 0
@@ -351,7 +364,8 @@ class GraphWal:
 
     def shard_wal(self, shard_id: int) -> ShardWal:
         return ShardWal(self.shard_dir(self.dir, shard_id), shard_id,
-                        fsync=self.fsync, fsync_every=self.fsync_every)
+                        fsync=self.fsync, fsync_every=self.fsync_every,
+                        spans=self.spans)
 
     # -- control appends ---------------------------------------------------
     def _append_control(self, epoch: int, record: dict) -> None:
@@ -360,11 +374,11 @@ class GraphWal:
         with self._lock:
             self._control_f.write(framed)
             if self.fsync == "always":
-                _fsync_file(self._control_f)
+                _fsync_file(self._control_f, self.spans)
             elif self.fsync == "batch":
                 self._control_synced += 1
                 if self._control_synced >= self.fsync_every:
-                    _fsync_file(self._control_f)
+                    _fsync_file(self._control_f, self.spans)
                     self._control_synced = 0
 
     def write_meta(self, params: dict) -> None:
@@ -392,13 +406,13 @@ class GraphWal:
     def sync(self) -> None:
         with self._lock:
             if self.fsync != "never":
-                _fsync_file(self._control_f)
+                _fsync_file(self._control_f, self.spans)
                 self._control_synced = 0
 
     def close(self) -> None:
         with self._lock:
             if self.fsync != "never":
-                _fsync_file(self._control_f)
+                _fsync_file(self._control_f, self.spans)
             self._control_f.close()
 
     # -- control scan (recovery) -------------------------------------------

@@ -68,6 +68,7 @@ from typing import Callable, Optional, Union
 
 import numpy as np
 
+from repro.core.spans import span
 from repro.core.versioned import Version
 from repro.graph.query import (ERR_BAD_QUERY, ERR_OVERLOADED, DegreeTopK,
                                KHop, PageRankQuery, Query, QueryRequest,
@@ -307,7 +308,8 @@ class GraphRPCServer:
             if not work.wait(timeout=0.2):
                 continue
             if self.batch_wait_s:
-                time.sleep(self.batch_wait_s)   # let a batch accumulate
+                with span(self.server.spans, "rpc.batch_wait", lane=lane):
+                    time.sleep(self.batch_wait_s)   # let a batch accumulate
             work.clear()
             # all-or-nothing window: on failure everything undelivered
             # was re-queued, so the retry below loses nothing
@@ -326,14 +328,29 @@ class GraphRPCServer:
 
     def _serve_conn(self, conn: socket.socket) -> None:
         send_lock = threading.Lock()   # per-connection: frames atomic
+        spans = self.server.spans
 
-        def reply(frame: dict) -> None:
-            data = encode_frame(frame)
+        def send(data: bytes, **args) -> None:
+            # counted first: no client holds bytes the counter has not seen
+            spans.count("sent_bytes", len(data))
             try:
-                with send_lock:
+                with send_lock, span(spans, "rpc.send", bytes=len(data),
+                                     **args):
                     conn.sendall(data)
             except OSError:
                 pass               # peer went away; reader will notice
+
+        def reply(frame: dict) -> None:
+            send(encode_frame(frame))
+
+        def answer(resp: QueryResponse) -> None:
+            # the spans name the answer: its request id, and its window
+            # when the scheduler's thread delivers it
+            args = {"window": self.server.current_window(),
+                    "request": resp.request_id}
+            with span(spans, "rpc.encode", **args):
+                data = encode_frame(encode_response(resp))
+            send(data, **args)
 
         try:
             while not self._stop.is_set():
@@ -343,13 +360,13 @@ class GraphRPCServer:
                     break
                 if frame is None:
                     break
-                self._handle(frame, reply)
+                self._handle(frame, reply, answer)
         finally:
             with self._conn_lock:
                 self._conns.discard(conn)
             conn.close()
 
-    def _handle(self, frame: dict, reply) -> None:
+    def _handle(self, frame: dict, reply, answer) -> None:
         rid = frame.get("id", 0)
         op = frame.get("op")
         if op == "stats":
@@ -361,8 +378,8 @@ class GraphRPCServer:
             reply({"id": rid, "ok": True, "latency_s": 0.0, "value": enc})
             return
         if op != "query":
-            reply(encode_response(QueryResponse.failed(
-                rid, ERR_BAD_QUERY, f"unknown op {op!r}")))
+            answer(QueryResponse.failed(
+                rid, ERR_BAD_QUERY, f"unknown op {op!r}"))
             return
         try:
             query = decode_query(frame.get("kind"),
@@ -374,13 +391,11 @@ class GraphRPCServer:
                              if pin is not None else None),
                 deadline_s=frame.get("deadline_s"))
         except (TypeError, ValueError, KeyError) as exc:
-            reply(encode_response(QueryResponse.failed(
-                rid, ERR_BAD_QUERY, str(exc))))
+            answer(QueryResponse.failed(rid, ERR_BAD_QUERY, str(exc)))
             return
-        shed = self.server.submit_request(
-            request, on_done=lambda resp: reply(encode_response(resp)))
+        shed = self.server.submit_request(request, on_done=answer)
         if shed is not None:       # typed overload/bad-query: answer NOW
-            reply(encode_response(shed))
+            answer(shed)
 
 
 # ------------------------------------------------------------- client
@@ -482,7 +497,8 @@ class GraphRPCClient:
         frame = read_frame(self._sock)
         if frame is None:
             raise ConnectionError("server closed the connection")
-        return decode_response(frame)
+        with span(None, "rpc.decode", request=frame.get("id")):
+            return decode_response(frame)
 
     def query(self, q: Query, *, pin_version: Optional[Version] = None,
               deadline_s: Optional[float] = None) -> QueryResponse:

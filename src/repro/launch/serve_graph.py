@@ -58,6 +58,7 @@ from typing import Callable, Iterable, Mapping, Optional, Sequence
 import numpy as np
 
 from repro.core.replica import MirrorPlanner
+from repro.core.spans import span
 from repro.core.versioned import Version
 from repro.graph.dyngraph import (JoinView, MutationBatch, prune_retired,
                                   prune_views, synthesize_churn_stream)
@@ -123,7 +124,25 @@ class ServerStats:
     trace prewarms that raised, ``dispatch_errors`` counts RPC dispatcher
     windows that failed for any reason other than "nothing sealed yet".
     Both are logged with their traceback; a healthy server keeps both
-    at 0."""
+    at 0.
+
+    Stage telemetry, cumulative since the store was built (read them as
+    deltas): ``span_s`` / ``span_n`` map each host span name to its
+    seconds and count — the RPC front's ``rpc.batch_wait``,
+    ``rpc.encode``, ``rpc.send``; the scheduler's ``serve.window``,
+    ``serve.deliver``; the engine's ``engine.route``, ``engine.pad``,
+    ``engine.upload``, ``engine.fetch``; the store's ``store.ingest``,
+    ``store.seal`` and ``store.apply`` (seconds only: the sum of the
+    store's ``shard_apply_seconds``, over the shards' threads); the
+    WAL's ``wal.append``, ``wal.fsync``; the publish's
+    ``serve.publish``, ``publish.stitch``, ``publish.replica_plan``.
+    The same names are ``jax.profiler`` annotations, so a trace of the
+    server process shows each stage on the device's clock.
+    ``queue_wait_s`` sums, over answered requests, the time from
+    submission to the drain of the window that answered them;
+    ``upload_bytes`` counts edge bytes copied to the device,
+    ``sent_bytes`` frame bytes the RPC front handed to its sockets,
+    ``wal_bytes`` shard record bytes appended to the log."""
     served: int
     windows: int
     queue_depth: int
@@ -167,6 +186,12 @@ class ServerStats:
     seal_failures: int = 0
     prewarm_errors: int = 0
     dispatch_errors: int = 0
+    span_s: Mapping[str, float] = dataclasses.field(default_factory=dict)
+    span_n: Mapping[str, int] = dataclasses.field(default_factory=dict)
+    queue_wait_s: float = 0.0
+    upload_bytes: int = 0
+    sent_bytes: int = 0
+    wal_bytes: int = 0
 
 
 class NothingSealedError(RuntimeError):
@@ -253,9 +278,14 @@ class GraphQueryServer:
                  max_touch_buffer: int = 65536,
                  **pagerank_kw):
         self.graph = graph
+        # one accumulator for every stage of this store's serving: the
+        # store's and its WAL's spans, the engine's, the scheduler's and
+        # the RPC front's (ServerStats exports it)
+        self.spans = graph.spans
         self.engine = SnapshotQueryEngine(
             result_cache=result_cache,
-            result_cache_entries=result_cache_entries, **pagerank_kw)
+            result_cache_entries=result_cache_entries, spans=self.spans,
+            **pagerank_kw)
         self.view_keep = view_keep
         self.rank_keep = rank_keep
         self.gc_every = max(1, gc_every)
@@ -330,7 +360,14 @@ class GraphQueryServer:
         self._lane_latencies: dict[str, collections.deque] = {
             lane: collections.deque(maxlen=4096) for lane in LANES}
         self.served = 0
+        # Σ (window drain - submission) over answered requests
+        self.queue_wait_s = 0.0
         self._auto_ids = itertools.count(1)
+        # window ids, drawn at each drain that finds work; the thread
+        # answering a window holds its id (current_window) while it
+        # delivers, so per-answer spans downstream can name it
+        self._window_ids = itertools.count(1)
+        self._local = threading.local()
         # dispatcher wake signals: work_available is the any-lane event
         # (legacy single-dispatcher waiters); work_cheap / work_expensive
         # wake the two-lane RPC dispatchers independently
@@ -377,37 +414,44 @@ class GraphQueryServer:
         stitch (O(delta), cached per version) is paid once per seal by the
         ingest side so no query ever stitches — or waits for the write
         lock — on its hot path."""
-        with self._ingest_lock:
-            v = self.graph.latest_sealed()
-            if v is None:
-                return
-            view = self.graph.join_view(v)
-            floor = self.graph.plan_floor()
-            routed = None
-            if self.replicate_hot:
-                # mirror refresh rides the publish: nominate from the
-                # ledger's vertex heat, rebuild the plan from THIS sealed
-                # version's own views — a mirror is exactly as fresh as
-                # the snapshot it serves, never staler (invariant I10)
-                hot = self._mirror_planner.nominate(
-                    self.graph.access_stats.vertex_heat)
-                plan = self.graph.build_replica_plan(v, hot)
-                routed = RoutedSnapshot(plan, self.graph.shard_views(v))
-        with self._serve_lock:
-            self._serving = (v, view, routed)
-            self._published[v.pack()] = view
-            # same ladder retention as the graph-side caches, and retired
-            # routing plans drop outright — but never the serving entry
-            prune_retired(self._published, floor)
-            prune_views(self._published, self.view_keep)
-        if self.prewarm_traces:
-            # hand the new snapshot to the prewarm worker (coalescing
-            # one-slot mailbox: a faster seal cadence overwrites the slot
-            # and the worker only ever warms the newest target)
-            with self._prewarm_lock:
-                self._prewarm_target = (v, view, routed)
-            self._prewarm_wake.set()
-            self._ensure_prewarm_thread()
+        with span(self.spans, "serve.publish") as publish:
+            with self._ingest_lock:
+                v = self.graph.latest_sealed()
+                if v is None:
+                    return
+                publish.note(epoch=v.epoch)
+                with span(self.spans, "publish.stitch", epoch=v.epoch):
+                    view = self.graph.join_view(v)
+                floor = self.graph.plan_floor()
+                routed = None
+                if self.replicate_hot:
+                    # mirror refresh rides the publish: nominate from the
+                    # ledger's vertex heat, rebuild the plan from THIS
+                    # sealed version's own views — a mirror is exactly as
+                    # fresh as the snapshot it serves, never staler (I10)
+                    with span(self.spans, "publish.replica_plan",
+                              epoch=v.epoch):
+                        hot = self._mirror_planner.nominate(
+                            self.graph.access_stats.vertex_heat)
+                        plan = self.graph.build_replica_plan(v, hot)
+                        routed = RoutedSnapshot(plan,
+                                                self.graph.shard_views(v))
+            with self._serve_lock:
+                self._serving = (v, view, routed)
+                self._published[v.pack()] = view
+                # same ladder retention as the graph-side caches, and
+                # retired routing plans drop outright — but never the
+                # serving entry
+                prune_retired(self._published, floor)
+                prune_views(self._published, self.view_keep)
+            if self.prewarm_traces:
+                # hand the new snapshot to the prewarm worker (coalescing
+                # one-slot mailbox: a faster seal cadence overwrites the
+                # slot and the worker only ever warms the newest target)
+                with self._prewarm_lock:
+                    self._prewarm_target = (v, view, routed)
+                self._prewarm_wake.set()
+                self._ensure_prewarm_thread()
 
     def _ensure_prewarm_thread(self) -> None:
         if self._prewarm_thread is not None or self._prewarm_stop.is_set():
@@ -726,6 +770,25 @@ class GraphQueryServer:
             self.work_expensive.set()
         if not pending:
             return []
+        window = next(self._window_ids)
+        self._local.window = window
+        with span(self.spans, "serve.window", lane=lane, window=window,
+                  queries=len(pending)):
+            return self._answer_window(pending, serving, now, window)
+
+    def current_window(self) -> Optional[int]:
+        """Id of the window the calling thread is answering (None on a
+        thread that has answered none): completion callbacks, which
+        :meth:`run_window` calls on its own thread, tag their spans with
+        it."""
+        return getattr(self._local, "window", None)
+
+    def _answer_window(self, pending: list[_Entry], serving, now: float,
+                       window: int
+                       ) -> list[tuple[QueryRequest, QueryResponse]]:
+        """:meth:`run_window` past the drain: answer the drained
+        entries ``pending`` (drained at ``now``) at the ``serving``
+        snapshot and deliver them."""
         expired: list[tuple[_Entry, QueryResponse]] = []
         live: list[_Entry] = []
         for e in pending:
@@ -754,7 +817,7 @@ class GraphQueryServer:
                 self._pending_expensive[:0] = [
                     e for e in live if e.lane != "cheap"]
                 self.shed_deadline += len(expired)
-            self._deliver(expired)
+            self._deliver(expired, window)
             raise NothingSealedError(
                 "no globally sealed snapshot yet — seal an epoch on "
                 "every shard before querying")
@@ -819,6 +882,7 @@ class GraphQueryServer:
         with self._serve_lock:
             self.windows += 1
             self.served += len(ok_entries)
+            self.queue_wait_s += sum(now - e.enqueued_at for e in ok_entries)
             self.shed_deadline += len(expired)
             for e in ok_entries:
                 lat = answered[id(e)].latency_s
@@ -847,16 +911,18 @@ class GraphQueryServer:
                              if x is e), None)
             if resp is not None:
                 pairs.append((e, resp))
-        self._deliver(pairs)
+        self._deliver(pairs, window)
         return [(e.request, r) for e, r in pairs]
 
-    @staticmethod
-    def _deliver(pairs: Sequence[tuple[_Entry, QueryResponse]]) -> None:
+    def _deliver(self, pairs: Sequence[tuple[_Entry, QueryResponse]],
+                 window: int) -> None:
         # completion callbacks run outside every lock: an RPC on_done
         # blocks on its connection's socket, never on the server
-        for e, resp in pairs:
-            if e.on_done is not None:
-                e.on_done(resp)
+        with span(self.spans, "serve.deliver", window=window,
+                  answers=len(pairs)):
+            for e, resp in pairs:
+                if e.on_done is not None:
+                    e.on_done(resp)
 
     def query(self, q: Query) -> QueryResult:
         """Answer a single query through the SAME shared scheduler as
@@ -940,6 +1006,9 @@ class GraphQueryServer:
             last_ingested = (Version.unpack(self.graph._last_version).epoch
                              if self.graph._last_version >= 0 else -1)
             stale_epochs = max(0, last_ingested - frontier)
+            apply_s = sum(self.graph.shard_apply_seconds)
+        span_s, span_n, counters = self.spans.snapshot()
+        span_s["store.apply"] = apply_s
         replica = self.engine.replica_stats()
         hist = replica["fanout_hist"]
         total_routed = sum(hist.values())
@@ -1008,7 +1077,13 @@ class GraphQueryServer:
                 stale_epochs=stale_epochs,
                 seal_failures=seal_failures,
                 prewarm_errors=prewarm_errors,
-                dispatch_errors=self.dispatch_errors)
+                dispatch_errors=self.dispatch_errors,
+                span_s=span_s,
+                span_n=span_n,
+                queue_wait_s=self.queue_wait_s,
+                upload_bytes=counters.get("upload_bytes", 0),
+                sent_bytes=counters.get("sent_bytes", 0),
+                wal_bytes=counters.get("wal_bytes", 0))
         return stats
 
 
