@@ -205,12 +205,13 @@ def reachability(view: JoinView, src: int, dst: int,
 
 # --------------------------------------------- batched/jitted online queries
 # Serving entry points: one jitted call answers a whole window of same-kind
-# queries. The traced functions are cached by (padded_m, n, S[, k]) shape:
+# queries. The compiled sweeps are cached by (padded_m, n, S[, k]) shape:
 # query sources are padded to a power-of-two width and the snapshot's edge
 # list to a power-of-two length (padding rows target a phantom segment ``n``
 # that is sliced off inside the kernel), so consecutive snapshots of a live
-# stream and windows of varying size hit the jit cache instead of retracing
-# per call.
+# stream and windows of varying size hit the cache instead of recompiling
+# per call. ``prepare_*`` compile a shape ahead of time, from its shapes
+# alone, so a server can make ready every width its routed windows can take.
 
 def pad_pow2(size: int, floor: int = 1) -> int:
     """Next power of two >= size (>= floor) — the padding rule the serving
@@ -223,14 +224,37 @@ def _padded_edges(view: JoinView,
     """(src, dst) with the edge list padded to a pow2 length; padded rows
     gather vertex 0 (harmless) and scatter into phantom segment ``n``
     (sliced off). Keeps the jitted query trace stable while a live stream
-    grows/shrinks m within the bucket."""
+    grows/shrinks m within the bucket. An edge list already at a pow2
+    length (a routed subset, padded on the host) passes through as is."""
     m = view.m
-    if not pad_edges:
-        return view.src, view.dst
     width = pad_pow2(m)
+    if not pad_edges or width == m:
+        return view.src, view.dst
     src = jnp.zeros((width,), view.src.dtype).at[:m].set(view.src)
     dst = jnp.full((width,), view.n, view.dst.dtype).at[:m].set(view.dst)
     return src, dst
+
+
+@functools.lru_cache(maxsize=256)
+def _compiled(program, shapes: tuple, statics: tuple):
+    """``program`` compiled ahead of time for arguments of ``shapes``
+    (``(shape, dtype)`` pairs) and the static arguments ``statics``."""
+    return program.lower(*(jax.ShapeDtypeStruct(s, d) for s, d in shapes),
+                         **dict(statics)).compile()
+
+
+def _shape(shape, dtype) -> tuple:
+    """A :func:`_compiled` key entry: the shape, and the dtype as the
+    device array will hold it."""
+    return tuple(shape), jnp.dtype(jax.dtypes.canonicalize_dtype(dtype))
+
+
+def _sweep(program, *args, **statics):
+    """Run a sweep program through :func:`_compiled`, so a shape that
+    :func:`prepare_k_hop` or :func:`prepare_reachability` compiled ahead
+    of time runs without compiling."""
+    shapes = tuple(_shape(a.shape, a.dtype) for a in args)
+    return _compiled(program, shapes, tuple(sorted(statics.items())))(*args)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "k"))
@@ -262,8 +286,20 @@ def batched_k_hop(view: JoinView, sources: jnp.ndarray, k: int, *,
     reach0 = jnp.zeros((view.n, width), bool).at[
         padded, jnp.arange(width)].set(True)
     src, dst = _padded_edges(view, pad_edges)
-    reach = _batched_khop(src, dst, reach0, view.n, int(k))
+    reach = _sweep(_batched_khop, src, dst, reach0, n=view.n, k=int(k))
     return reach.T[:s]
+
+
+def prepare_k_hop(n: int, k: int, sources: int, edges: int,
+                  dtype=jnp.int32) -> None:
+    """Compile :func:`batched_k_hop`'s sweep ahead of time, with no
+    device work: ``n`` vertices, ``k`` hops, a window padded to
+    ``sources`` and ``edges`` rows of ``dtype``, already a power of two
+    (as the routed subsets are)."""
+    edge = _shape((edges,), dtype)
+    _compiled(_batched_khop,
+              (edge, edge, _shape((n, sources), bool)),
+              (("k", int(k)), ("n", int(n))))
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
@@ -311,9 +347,21 @@ def batched_reachability(view: JoinView, src_ids: jnp.ndarray,
         psrc, jnp.arange(width)].set(True)
     # falsy max_hops (None or 0) means unbounded — same promotion the
     # scalar reachability() applies, so the two entry points agree
-    hops = jnp.asarray(max_hops or view.n)
+    hops = jnp.asarray(max_hops or view.n, jnp.int32)
     src, dst = _padded_edges(view, pad_edges)
-    return _batched_reach(src, dst, reach0, pdst, hops, view.n)[:s]
+    return _sweep(_batched_reach, src, dst, reach0, pdst, hops,
+                  n=view.n)[:s]
+
+
+def prepare_reachability(n: int, sources: int, edges: int,
+                         dtype=jnp.int32) -> None:
+    """:func:`prepare_k_hop` for :func:`batched_reachability`'s sweep
+    (int32 source and target ids, as the query engine passes them)."""
+    edge = _shape((edges,), dtype)
+    _compiled(_batched_reach,
+              (edge, edge, _shape((n, sources), bool),
+               _shape((sources,), jnp.int32), _shape((), jnp.int32)),
+              (("n", int(n)),))
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

@@ -34,7 +34,10 @@ inner per-version dict is capped (``result_cache_entries``). The engine
 also records the jit-trace *signatures* windows actually hit (kind,
 static args, pow2-padded source width) so :meth:`SnapshotQueryEngine
 .warm_traces` — the publish-time prewarm — can retrace exactly the
-shapes real clients use against a new snapshot's edge bucket.
+shapes real clients use against a new snapshot's edge bucket. A routed
+window's rows are a frontier closure whose size varies from window to
+window, so each frontier signature is also compiled, ahead of time, at
+every width in :func:`routed_widths` of the snapshot it is routed on.
 
 The engine is deliberately snapshot-agnostic — the serving loop
 (``launch.serve_graph``) picks WHICH snapshot (always
@@ -46,7 +49,9 @@ re-sharding planner described there.
 from __future__ import annotations
 
 import dataclasses
+import os
 import threading
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Sequence, Union
 
 import jax
@@ -268,6 +273,31 @@ class RoutedSnapshot:
 _MISS = object()          # result-cache sentinel (None is a legal value)
 
 
+def _speaks_for(routed: Optional[RoutedSnapshot], view: JoinView) -> bool:
+    """The coherence gate: a RoutedSnapshot speaks only for its own
+    sealed version (I10)."""
+    return routed is not None \
+        and routed.plan.version.pack() == view.version.pack()
+
+
+# a routed window's rows are padded to a power of two and to no fewer
+# than this many (a sweep over 2**16 rows takes milliseconds on a TPU
+# v5e), so a snapshot's routed sweeps take few widths, all of them
+# compiled ahead of time
+MIN_ROUTED_WIDTH = 1 << 16
+
+
+def routed_widths(m: int) -> list[int]:
+    """The row widths a routed sweep over a snapshot of ``m`` rows is
+    padded to, narrowest first: the powers of two from
+    :data:`MIN_ROUTED_WIDTH` (or ``pad_pow2(m)``, if smaller) up to
+    ``pad_pow2(m)``, since a routed subset never holds more rows than
+    its snapshot. 11 widths for 2**26 rows."""
+    top = gc.pad_pow2(m)
+    low = min(top, MIN_ROUTED_WIDTH)
+    return [low << i for i in range((top // low).bit_length())]
+
+
 def _freeze_result(val: object) -> object:
     """Make a to-be-memoized value safe to hand out by reference. Cache
     hits return the stored object itself, so an in-process caller that
@@ -301,7 +331,8 @@ class SnapshotQueryEngine:
 
     ``spans`` is the accumulator the engine's spans (``engine.route``,
     ``engine.pad``, ``engine.upload``, ``engine.fetch``) and its
-    ``upload_bytes`` counter add to; the serving layer passes its own.
+    ``routed_rows`` and ``upload_bytes`` counters add to; the serving
+    layer passes its own.
     """
 
     def __init__(self, *, result_cache: bool = True,
@@ -337,7 +368,8 @@ class SnapshotQueryEngine:
         # width IS a new trace), so steady-state publishes cost nothing —
         # a replay executes the kernel for real, and burning a core on
         # sweeps whose traces are already warm starves serving on small
-        # hosts for zero cache benefit
+        # hosts for zero cache benefit. (signature, n, routed width)
+        # triples mark the routed widths compiled ahead of time.
         self._warmed_traces: set[tuple] = set()
         # replica-plane telemetry (same lock): per frontier vertex, did
         # its adjacency come from a mirror; per routed group, how many
@@ -446,14 +478,14 @@ class SnapshotQueryEngine:
                     "fanout_hist": dict(self.fanout_hist)}
 
     def _route(self, routed: Optional[RoutedSnapshot], view: JoinView,
-               anchors: np.ndarray, hops: Optional[int], *,
-               record: bool = True) -> Optional[_SubView]:
+               anchors: np.ndarray,
+               hops: Optional[int]) -> Optional[_SubView]:
         """Resolve one same-kind group through the replica plane, or None
         to fall back to the global view. The version check is the
         coherence gate: a RoutedSnapshot only ever speaks for its own
         sealed version, so a pinned replay at another version can never
         be answered from these mirrors."""
-        if routed is None or routed.plan.version.pack() != view.version.pack():
+        if not _speaks_for(routed, view):
             return None
         with span(self.spans, "engine.route", hops=hops,
                   anchors=int(anchors.size)) as s:
@@ -465,12 +497,13 @@ class SnapshotQueryEngine:
         # the sliced-off segment). Routed edge counts vary per window —
         # handing raw lengths to ``_padded_edges`` would compile its
         # eager pad op once per distinct m; pre-bucketing collapses
-        # routed windows onto a few stable shapes, so the replica path
-        # keeps its traces warm even while the global CSR drifts
-        width = gc.pad_pow2(sub_src.size)
+        # routed windows onto the few widths of ``routed_widths``, all
+        # compiled ahead of time
+        rows = int(sub_src.size)
+        width = gc.pad_pow2(max(rows, 1), floor=routed_widths(view.m)[0])
         with span(self.spans, "engine.pad", rows=width):
-            if width > sub_src.size:
-                extra = width - sub_src.size
+            if width > rows:
+                extra = width - rows
                 sub_src = np.concatenate(
                     [sub_src, np.zeros(extra, sub_src.dtype)])
                 sub_dst = np.concatenate(
@@ -480,16 +513,13 @@ class SnapshotQueryEngine:
         with span(self.spans, "engine.upload", rows=width):
             dev_src, dev_dst = jax.block_until_ready(
                 jax.device_put((sub_src, sub_dst)))
+        self.spans.count("routed_rows", rows)
         self.spans.count("upload_bytes", sub_src.nbytes + sub_dst.nbytes)
-        if record:
-            # prewarm passes record=False: a trace-warming sweep must not
-            # pollute the mirror-hit / fan-out telemetry real windows feed
-            with self._rank_lock:
-                self.mirror_hits += hits
-                self.mirror_misses += misses
-                self.routed_windows += 1
-                self.fanout_hist[fanout] = \
-                    self.fanout_hist.get(fanout, 0) + 1
+        with self._rank_lock:
+            self.mirror_hits += hits
+            self.mirror_misses += misses
+            self.routed_windows += 1
+            self.fanout_hist[fanout] = self.fanout_hist.get(fanout, 0) + 1
         return _SubView(view.n, dev_src, dev_dst)
 
     def _resident(self, view: JoinView) -> JoinView:
@@ -566,10 +596,12 @@ class SnapshotQueryEngine:
                 slot[fp] = _freeze_result(values[i])
         return values
 
-    def _record_signatures(self, khops, reaches, topks, n: int) -> None:
+    def _record_signatures(self, khops, reaches, topks,
+                           n: int) -> list[tuple]:
         """Remember the jit-trace signatures this window hit so a later
-        :meth:`warm_traces` can replay them against a new snapshot.
-        Insertion-ordered with a cap: overflow drops the stalest."""
+        :meth:`warm_traces` can replay them against a new snapshot, and
+        return them. Insertion-ordered with a cap: overflow drops the
+        stalest."""
         sigs = []
         for k, idxs in khops.items():
             sigs.append(("k_hop", int(k), gc.pad_pow2(len(idxs))))
@@ -578,7 +610,7 @@ class SnapshotQueryEngine:
         for (k, direction), _idxs in topks.items():
             sigs.append(("degree_topk", min(int(k), n), direction))
         if not sigs:
-            return
+            return sigs
         with self._rank_lock:
             for sig in sigs:
                 self._warm_signatures.pop(sig, None)   # refresh recency
@@ -586,10 +618,47 @@ class SnapshotQueryEngine:
             while len(self._warm_signatures) > MAX_WARM_SIGNATURES:
                 self._warm_signatures.pop(
                     next(iter(self._warm_signatures)))
+        return sigs
+
+    def _fresh(self, key: tuple) -> bool:
+        """Mark ``key`` warmed; False if it already was."""
+        with self._rank_lock:
+            if key in self._warmed_traces:
+                return False
+            if len(self._warmed_traces) > 4096:   # distinct widths are
+                self._warmed_traces.clear()       # few; belt and braces
+            self._warmed_traces.add(key)
+        return True
+
+    def _prepare_routed(self, sigs: Sequence[tuple], view: JoinView,
+                        routed: RoutedSnapshot) -> int:
+        """Compile the sweep of each frontier signature in ``sigs`` at
+        every width of ``routed_widths(view.m)``, ahead of time and with
+        no device work, so that no routed window of these signatures on
+        this snapshot compiles. The compiles run side by side (the
+        compiler releases the GIL). Returns the number of programs
+        compiled (0 once every width is ready)."""
+        dtype = routed.plan.mirror_src.dtype
+        todo = [(sig, width) for sig in sigs
+                if sig[0] in ("k_hop", "reachability")
+                for width in routed_widths(view.m)
+                if self._fresh((sig, view.n, width))]
+
+        def prepare(job):
+            sig, width = job
+            if sig[0] == "k_hop":
+                gc.prepare_k_hop(view.n, sig[1], sig[2], width, dtype)
+            else:
+                gc.prepare_reachability(view.n, sig[1], width, dtype)
+
+        if todo:
+            with ThreadPoolExecutor(min(len(todo),
+                                        os.cpu_count() or 1)) as pool:
+                list(pool.map(prepare, todo))
+        return len(todo)
 
     def warm_traces(self, view: JoinView,
-                    routed: Optional[RoutedSnapshot] = None, *,
-                    max_anchors: int = 8) -> int:
+                    routed: Optional[RoutedSnapshot] = None) -> int:
         """Publish-time trace prewarm: replay every recorded jit-trace
         signature against ``view`` so the first real query after a seal
         pays a dict-cache hit, not a compile/retrace.
@@ -599,10 +668,9 @@ class SnapshotQueryEngine:
         and the first window at the new bucket pays the retrace. Running
         the recorded signatures here (on the ingest side's background
         prewarm thread, against the freshly published immutable view)
-        moves that cost off the query path. With ``routed``, the hottest
-        ``max_anchors`` mirrored vertices additionally warm the
-        replica-routed buckets (via :meth:`_route` with telemetry
-        recording off — prewarm is invisible in the mirror stats).
+        moves that cost off the query path. With ``routed``, the
+        frontier signatures are also compiled at every routed width of
+        the snapshot (:meth:`_prepare_routed`: compiles, not sweeps).
 
         Idempotent and safe to race with queries or the next seal: it
         only reads the immutable snapshot and the jit trace caches, and
@@ -611,59 +679,32 @@ class SnapshotQueryEngine:
         the width is the trace key, so replaying a combination that
         already ran would execute a full kernel sweep for a guaranteed
         jit-cache hit; steady-state publishes (no bucket step) are
-        therefore near-free. Returns the number of replays executed
-        (0 once everything recorded is warm at the current widths)."""
+        therefore near-free. Returns the number of replays and compiles
+        executed (0 once everything recorded is warm at the current
+        widths)."""
         with self._rank_lock:
             sigs = list(self._warm_signatures)
-        hot = None
-        if routed is not None \
-                and routed.plan.version.pack() == view.version.pack() \
-                and routed.plan.n_mirrored:
-            hot = np.flatnonzero(routed.plan.mirrored)[:max_anchors] \
-                .astype(np.int32)
         m = view.m
         warmed = 0
-
-        def fresh(key):
-            with self._rank_lock:
-                if key in self._warmed_traces:
-                    return False
-                if len(self._warmed_traces) > 4096:   # distinct widths are
-                    self._warmed_traces.clear()       # few; belt and braces
-                self._warmed_traces.add(key)
-            return True
-
         for sig in sigs:
+            if not self._fresh((sig, m)):
+                continue
             if sig[0] == "k_hop":
                 _, k, width = sig
-                anchors = np.zeros(width, np.int32)
-                if fresh((sig, m)):
-                    gc.batched_k_hop(self._resident(view), anchors, k)
-                    warmed += 1
-                if hot is not None:
-                    sub = self._route(routed, view, hot, k, record=False)
-                    if sub is not None and fresh((sig, int(sub.src.size))):
-                        gc.batched_k_hop(sub, anchors, k)
-                        warmed += 1
+                gc.batched_k_hop(self._resident(view),
+                                 np.zeros(width, np.int32), k)
             elif sig[0] == "reachability":
-                _, width = sig
-                anchors = np.zeros(width, np.int32)
+                anchors = np.zeros(sig[1], np.int32)
                 # src == dst, so the while_loop exits on round one: the
                 # warm is the trace, not a graph sweep
-                if fresh((sig, m)):
-                    gc.batched_reachability(self._resident(view), anchors,
-                                            anchors, 1)
-                    warmed += 1
-                if hot is not None:
-                    sub = self._route(routed, view, hot, 1, record=False)
-                    if sub is not None and fresh((sig, int(sub.src.size))):
-                        gc.batched_reachability(sub, anchors, anchors, 1)
-                        warmed += 1
+                gc.batched_reachability(self._resident(view), anchors,
+                                        anchors, 1)
             elif sig[0] == "degree_topk":
                 _, k, direction = sig
-                if fresh((sig, m)):
-                    gc.degree_topk(view, k, direction=direction)
-                    warmed += 1
+                gc.degree_topk(view, k, direction=direction)
+            warmed += 1
+        if _speaks_for(routed, view):
+            warmed += self._prepare_routed(sigs, view, routed)
         return warmed
 
     def _execute_groups(self, view: JoinView, queries: Sequence[Query],
@@ -689,7 +730,11 @@ class SnapshotQueryEngine:
                 ranks.append(i)
             else:
                 raise TypeError(f"unknown query type {type(q).__name__}")
-        self._record_signatures(khops, reaches, topks, view.n)
+        sigs = self._record_signatures(khops, reaches, topks, view.n)
+        if _speaks_for(routed, view):
+            # before the first routed sweep of a signature on this
+            # snapshot: every width a later window's closure can take
+            self._prepare_routed(sigs, view, routed)
 
         for k, idxs in khops.items():
             sources = np.asarray([queries[i].source for i in idxs], np.int32)
@@ -707,8 +752,9 @@ class SnapshotQueryEngine:
             srcs = np.asarray([queries[i].src for i in idxs], np.int32)
             dsts = np.asarray([queries[i].dst for i in idxs], np.int32)
             # frontier expansion only ever walks forward from the
-            # sources, so they alone anchor the route
-            target = (self._route(routed, view, srcs, max_hops)
+            # sources, so they alone anchor the route; a falsy hop bound
+            # is unbounded, as in the sweep
+            target = (self._route(routed, view, srcs, max_hops or None)
                       or self._resident(view))
             got = gc.batched_reachability(target, srcs, dsts, max_hops)
             with span(self.spans, "engine.fetch", queries=len(idxs)):

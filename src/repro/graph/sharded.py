@@ -629,67 +629,67 @@ class ReplicaPlan:
 def replica_route(plan: ReplicaPlan, shard_views: list[JoinView],
                   anchors, hops: Optional[int]) -> tuple[
                       np.ndarray, np.ndarray, int, int, int]:
-    """Replica-first routing for one same-kind window: compute the union
+    """Replica-first routing for one same-kind window: walk the union
     frontier closure of ``anchors`` (k-hop sources / reachability sources)
-    out to ``hops`` expansions (None = until the frontier drains), pulling
-    each hop's neighbors from the MIRROR for mirrored frontier vertices
-    and only from shards whose ``src_presence`` says they hold out-edges
-    of the non-mirrored rest.
+    for ``hops`` expansions (None = until the frontier drains), pulling
+    each expansion's out-edges from the MIRROR for mirrored frontier
+    vertices and only from shards whose ``src_presence`` says they hold
+    out-edges of the non-mirrored rest.
 
     Returns ``(sub_src, sub_dst, fanout, mirror_hits, mirror_misses)``:
-    the restricted edge set (mirror rows + full rows of every shard
-    touched), the number of distinct shards touched, and per-vertex
-    mirror hit/miss counts. The edge set contains every out-edge of every
-    vertex whose edges a ``hops``-step frontier sweep from ``anchors`` can
-    read — mirrors are complete per vertex and presence is exact per
-    (shard, vertex) — and only rows of the same sealed snapshot, so
-    running the ordinary batched kernels on it is byte-identical to
-    running them on the stitched global view (the replica-plane
-    equivalence tests assert exactly this across split and merge
-    cutovers)."""
+    the rows those expansions read, the number of distinct shards read,
+    and per-vertex mirror hit/miss counts. The rows are exactly the
+    out-edges of every vertex within ``hops - 1`` hops of some anchor
+    (of every vertex reachable from one, for None), each once: mirrors
+    are complete per vertex, presence is exact per (shard, vertex), and
+    a vertex joins the frontier once. A ``hops``-step frontier sweep from
+    these anchors reads no other row (a row whose source is further out
+    can set no bit within ``hops`` steps), and the rows are the same
+    sealed snapshot's, so running the ordinary batched kernels on them is
+    byte-identical to running them on the stitched global view (the
+    replica-plane equivalence tests assert exactly this across split and
+    merge cutovers)."""
     n = plan.mirrored.shape[0]
     ids = np.asarray(anchors, np.int64).reshape(-1)
     frontier = np.unique(ids[(ids >= 0) & (ids < n)])
     reached = np.zeros(n, bool)
     reached[frontier] = True
     touched = np.zeros(len(shard_views), bool)
-    use_mirror = False
     hits = misses = 0
     fmask = np.empty(n, bool)
+    src_parts: list[np.ndarray] = []
+    dst_parts: list[np.ndarray] = []
     expansions = n if hops is None else int(hops)
-    for _ in range(expansions):
+    for step in range(expansions):
         if not frontier.size:
             break
         is_m = plan.mirrored[frontier]
         f_mir, f_rest = frontier[is_m], frontier[~is_m]
         hits += int(f_mir.size)
         misses += int(f_rest.size)
-        parts = []
+        first = len(dst_parts)
         if f_mir.size:
-            use_mirror = True
             fmask[:] = False
             fmask[f_mir] = True
-            parts.append(plan.mirror_dst[fmask[plan.mirror_src]])
+            rows = np.flatnonzero(fmask[plan.mirror_src])
+            src_parts.append(plan.mirror_src[rows])
+            dst_parts.append(plan.mirror_dst[rows])
         if f_rest.size:
-            touched |= plan.src_presence[:, f_rest].any(axis=1)
+            holders = plan.src_presence[:, f_rest].any(axis=1)
+            touched |= holders
             fmask[:] = False
             fmask[f_rest] = True
-            for j in np.flatnonzero(plan.src_presence[:, f_rest]
-                                    .any(axis=1)):
+            for j in np.flatnonzero(holders):
                 v = shard_views[j]
-                parts.append(v.np_dst[fmask[v.np_src]])
-        if not parts:
-            break
-        neigh = np.concatenate(parts).astype(np.int64, copy=False)
+                rows = np.flatnonzero(fmask[v.np_src])
+                src_parts.append(v.np_src[rows])
+                dst_parts.append(v.np_dst[rows])
+        if step + 1 == expansions or len(dst_parts) == first:
+            break       # the last frontier's successors are never read
+        neigh = np.concatenate(dst_parts[first:]).astype(np.int64,
+                                                         copy=False)
         frontier = np.unique(neigh[~reached[neigh]])
         reached[frontier] = True
-    src_parts, dst_parts = [], []
-    if use_mirror:
-        src_parts.append(plan.mirror_src)
-        dst_parts.append(plan.mirror_dst)
-    for j in np.flatnonzero(touched):
-        src_parts.append(shard_views[j].np_src)
-        dst_parts.append(shard_views[j].np_dst)
     if src_parts:
         sub_src = np.concatenate(src_parts)
         sub_dst = np.concatenate(dst_parts)

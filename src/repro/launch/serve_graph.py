@@ -140,7 +140,9 @@ class ServerStats:
     server process shows each stage on the device's clock.
     ``queue_wait_s`` sums, over answered requests, the time from
     submission to the drain of the window that answered them;
-    ``upload_bytes`` counts edge bytes copied to the device,
+    ``routed_rows`` counts the edge rows replica routing selected for
+    the frontier sweeps, before their pow2 pad; ``upload_bytes`` counts
+    edge bytes copied to the device,
     ``sent_bytes`` frame bytes the RPC front handed to its sockets,
     ``wal_bytes`` shard record bytes appended to the log."""
     served: int
@@ -189,6 +191,7 @@ class ServerStats:
     span_s: Mapping[str, float] = dataclasses.field(default_factory=dict)
     span_n: Mapping[str, int] = dataclasses.field(default_factory=dict)
     queue_wait_s: float = 0.0
+    routed_rows: int = 0
     upload_bytes: int = 0
     sent_bytes: int = 0
     wal_bytes: int = 0
@@ -1081,6 +1084,7 @@ class GraphQueryServer:
                 span_s=span_s,
                 span_n=span_n,
                 queue_wait_s=self.queue_wait_s,
+                routed_rows=counters.get("routed_rows", 0),
                 upload_bytes=counters.get("upload_bytes", 0),
                 sent_bytes=counters.get("sent_bytes", 0),
                 wal_bytes=counters.get("wal_bytes", 0))

@@ -17,6 +17,10 @@ The hypothesis property tests (routing determinism given (plan, ledger);
 routed-answer equivalence) self-skip when hypothesis is absent, like
 ``tests/test_resharding.py``; deterministic variants always run.
 """
+import os
+import sys
+import threading
+
 import numpy as np
 import pytest
 
@@ -41,8 +45,9 @@ from repro.core.replica import MirrorPlanner, ShardPlanner
 from repro.core.versioned import Version
 from repro.graph import compute as gc
 from repro.graph.dyngraph import synthesize_churn_stream
+from repro.graph import query
 from repro.graph.query import (KHop, Reachability, RoutedSnapshot,
-                               SnapshotQueryEngine, _SubView)
+                               SnapshotQueryEngine, _SubView, routed_widths)
 from repro.graph.reference import LoopDynamicGraph
 from repro.graph.sharded import (RoutingPlan, ShardedDynamicGraph,
                                  replica_route)
@@ -246,6 +251,132 @@ def test_replica_route_byte_identical(seed):
     _, _, fanout, hits, misses = replica_route(
         rp, views, np.array([1, 2, 3]), 1)
     assert fanout == 0 and misses == 0 and hits == 3
+
+
+def _closure_rows(g, anchors, hops):
+    """Brute force on the global view: the (src, dst) rows of every
+    vertex within ``hops - 1`` hops of ``anchors`` (every vertex
+    reachable from them, for None), sorted."""
+    reached = np.zeros(g.n, bool)
+    reached[anchors] = True
+    frontier, read = reached.copy(), np.zeros(g.n, bool)
+    for _ in range(g.n if hops is None else hops):
+        read |= frontier
+        nxt = np.zeros(g.n, bool)
+        nxt[g.np_dst[frontier[g.np_src]]] = True
+        frontier = nxt & ~reached
+        reached |= nxt
+    sel = read[g.np_src]
+    return sorted(zip(g.np_src[sel].tolist(), g.np_dst[sel].tolist()))
+
+
+@pytest.mark.parametrize("k_hot", [0, 4, 40])
+@pytest.mark.parametrize("hops", [1, 2, 3, "reach-2", "reach-unbounded"])
+def test_replica_route_is_the_frontier_closure(k_hot, hops):
+    """The routed rows are exactly the multiset of out-edges of the
+    vertices within k - 1 hops of the anchors, whatever part of them the
+    mirror serves, and no row of any shard beyond them."""
+    hops = {"reach-2": 2, "reach-unbounded": None}.get(hops, hops)
+    sg, v = _routed_store(4)
+    g = sg.join_view(v)
+    views = sg.shard_views(v)
+    rng = np.random.default_rng(k_hot)
+    hot = rng.choice(40, size=k_hot, replace=False) if k_hot else \
+        np.zeros(0, np.int64)
+    rp = sg.build_replica_plan(v, hot)
+    for anchors in (rng.integers(0, 40, 3), rng.integers(0, 40, 1)):
+        sub_src, sub_dst, fanout, hits, misses = replica_route(
+            rp, views, anchors.astype(np.int32), hops)
+        want = _closure_rows(g, anchors, hops)
+        assert sorted(zip(sub_src.tolist(), sub_dst.tolist())) == want
+        assert 0 <= fanout <= len(views)
+    # one hop from one vertex is a partial closure: fewer rows than the
+    # shards hold, though every shard holding one of them is read
+    sub_src, _, fanout, _, _ = replica_route(rp, views, [int(g.np_src[0])],
+                                             1)
+    assert 0 < sub_src.size < sum(sv.m for sv in views)
+
+
+def test_routed_reachability_with_a_zero_hop_bound_is_unbounded():
+    """A hop bound of 0 means unbounded on every path, routed too."""
+    sg, v = _routed_store(0)
+    g = sg.join_view(v)
+    routed = RoutedSnapshot(sg.build_replica_plan(v, np.arange(4)),
+                            sg.shard_views(v))
+    qs = [Reachability(s, d, max_hops=0)
+          for s, d in ((1, 30), (2, 17), (5, 9), (3, 33))]
+    got = SnapshotQueryEngine().execute(g, qs, routed=routed)
+    assert got == SnapshotQueryEngine().execute(g, qs)
+    assert any(got)
+
+
+def test_routed_windows_of_new_widths_do_not_compile(monkeypatch):
+    """After a k-hop signature's first routed window, windows whose
+    closures land in other pow2 row widths run without a compile (with
+    the floor under the routed width lowered to fit a small store)."""
+    from jax import monitoring
+    monkeypatch.setattr(query, "MIN_ROUTED_WIDTH", 4)
+    sg, v = _routed_store(5, n=64, epochs=8)
+    g = sg.join_view(v)
+    routed = RoutedSnapshot(sg.build_replica_plan(v, np.zeros(0)),
+                            sg.shard_views(v))
+    eng = SnapshotQueryEngine(result_cache=False)
+    def routed_width(anchors):
+        rows = replica_route(routed.plan, routed.shard_views, anchors, 2)[0]
+        return gc.pad_pow2(max(rows.size, 1), floor=routed_widths(g.m)[0])
+    # windows of two sources, grouped by the width their closure pads to
+    by_width = {}
+    for a in range(64):
+        for b in range(a + 1, 64):
+            by_width.setdefault(routed_width([a, b]), [a, b])
+    assert len(by_width) >= 3, by_width
+    first, *rest = by_width.values()
+    eng.execute(g, [KHop(s, k=2) for s in first], routed=routed)
+    wants = [np.asarray(gc.batched_k_hop(g, np.asarray(a), 2)) for a in rest]
+    compiles = []
+
+    def count(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+    monitoring.register_event_duration_secs_listener(count)
+    try:
+        for anchors, want in zip(rest, wants, strict=True):
+            got = eng.execute(g, [KHop(s, k=2) for s in anchors],
+                              routed=routed)
+            np.testing.assert_array_equal(np.asarray(got), want)
+    finally:
+        monitoring.unregister_event_duration_listener(count)
+    assert compiles == []
+
+
+def test_racing_prepares_compile_each_routed_width_once(monkeypatch):
+    """Windows and the publish-time prewarm racing to make a snapshot's
+    routed widths ready: each (signature, width) is compiled by exactly
+    one of them, and a later window at any width compiles nothing."""
+    monkeypatch.setattr(query, "MIN_ROUTED_WIDTH", 4)
+    sg, v = _routed_store(6)
+    g = sg.join_view(v)
+    routed = RoutedSnapshot(sg.build_replica_plan(v, np.zeros(0)),
+                            sg.shard_views(v))
+    eng = SnapshotQueryEngine()
+    sigs = [("k_hop", 2, 4), ("reachability", 4)]
+    done = []
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        racers = [threading.Thread(target=lambda: done.append(
+            eng._prepare_routed(sigs, g, routed)))
+            for _ in range((os.cpu_count() or 1) + 2)]
+        for t in racers:
+            t.start()
+        for t in racers:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in racers)
+    finally:
+        sys.setswitchinterval(old)
+    assert len(done) == len(racers)
+    assert sum(done) == len(sigs) * len(routed_widths(g.m))
+    assert eng._prepare_routed(sigs, g, routed) == 0
 
 
 def test_engine_routed_execution_and_telemetry():

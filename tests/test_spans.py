@@ -9,7 +9,8 @@ import pytest
 from repro.core.spans import Spans, span
 from repro.graph import compute as gc
 from repro.graph.dyngraph import synthesize_churn_stream
-from repro.graph.query import KHop
+from repro.graph import query
+from repro.graph.query import KHop, routed_widths
 from repro.graph.sharded import ShardedDynamicGraph, replica_route
 from repro.launch.serve_graph import GraphQueryServer
 
@@ -103,7 +104,7 @@ def test_a_routed_window_counts_its_upload_and_its_queue_wait(tmp_path):
         sub_src, _, _, _, _ = replica_route(
             routed.plan, routed.shard_views,
             np.asarray(sources, np.int32), 2)
-        rows = gc.pad_pow2(sub_src.size)
+        rows = gc.pad_pow2(sub_src.size, floor=routed_widths(view.m)[0])
         assert after.upload_bytes - before.upload_bytes == 8 * rows
         for name in ("serve.window", "serve.deliver", "engine.route",
                      "engine.pad", "engine.upload", "engine.fetch"):
@@ -114,6 +115,35 @@ def test_a_routed_window_counts_its_upload_and_its_queue_wait(tmp_path):
         want = np.asarray(gc.batched_k_hop(view, np.asarray(sources), 2))
         for row, r in zip(want, got, strict=True):
             assert np.array_equal(row, r.value)
+    finally:
+        for w in [*store.wal_shards, store.wal]:
+            w.close()
+
+
+@pytest.mark.parametrize("k", [1, 2, 3])
+def test_a_routed_window_counts_its_routed_rows(tmp_path, monkeypatch, k):
+    """``routed_rows`` grows by the window's routed rows before the pad,
+    and the upload is those rows padded to a power of two (with no
+    floor under the pad, which a store this small would reach)."""
+    monkeypatch.setattr(query, "MIN_ROUTED_WIDTH", 1)
+    store, server, _ = _loaded(tmp_path)
+    try:
+        sources = [2, 11, 40, 77]
+        for v in sources:
+            server.submit(KHop(v, k=k))
+        before = server.stats()
+        server.flush()
+        after = server.stats()
+        _, view, routed = server._serving
+        sub_src, _, _, _, _ = replica_route(
+            routed.plan, routed.shard_views,
+            np.asarray(sources, np.int32), k)
+        routed_rows = after.routed_rows - before.routed_rows
+        assert routed_rows == sub_src.size > 0
+        assert after.upload_bytes - before.upload_bytes == \
+            8 * gc.pad_pow2(routed_rows)
+        if k == 1:
+            assert routed_rows < view.m
     finally:
         for w in [*store.wal_shards, store.wal]:
             w.close()
