@@ -16,7 +16,11 @@ The control answers every request from a partly sealed snapshot: the load
 epoch applied to three quarters of the rows only (those whose destination
 is not 0 mod 4), which breaks "answers at sealed snapshots only". The
 configuration states no precision, and its answers are exact sets, so
-there is no lower precision to compute them in.
+there is no lower precision to compute them in. In a run with an update
+stream the control is stale instead: each answer stamped at an epoch
+``e >= 1`` is the reference's answer at ``e - 1`` (answers stamped at the
+load have no older snapshot and are left out), which breaks "answers at
+the stamped snapshot".
 """
 from __future__ import annotations
 
@@ -41,9 +45,49 @@ def partial_graph(graph: graphgen.Graph) -> graphgen.Graph:
                                dst=graph.dst[keep])
 
 
-def control_readings(run) -> dict:
-    """Each compared number of the answers as the control reads it."""
+def answer(snaps: reference.Snapshots, q, epoch: int):
+    """The reference's answer to ``q`` at ``epoch``, as the server shapes
+    it."""
     from repro.graph.query import query_kind
+    kind = query_kind(q)
+    if kind == "degree_topk":
+        return snaps.degree_topk(epoch, q.k, q.direction)
+    if kind == "pagerank":
+        if q.top_k is None:
+            return snaps.ranks(epoch)
+        return snaps.top_ranks(epoch, q.top_k)
+    host = snaps.host(epoch)
+    if kind == "k_hop":
+        return host.within_hops(q.source, q.k)
+    return bool(host.within_hops(q.src, q.max_hops)[q.dst])
+
+
+def compared(run) -> list:
+    """The answers the check compared, oldest epoch first: PageRank
+    answers only at its sampled epochs (``run.checked.rank_at``)."""
+    from repro.graph.query import query_kind
+    rank_at = run.checked.rank_at
+    return sorted((r for r in run.answered() if r.epoch is not None and (
+        query_kind(r.query) != "pagerank" or rank_at is None
+        or r.epoch in rank_at)), key=lambda r: r.epoch)
+
+
+def stale_readings(run, snaps: reference.Snapshots) -> dict:
+    """Each compared number of the stale control's answers."""
+    published = [e.epoch for e in run.epochs if e.published]
+    stale = [dataclasses.replace(r, value=answer(snaps, r.query, r.epoch - 1))
+             for r in compared(run) if r.epoch >= 1]
+    values, _ = checks.check_answers(stale, run.graph, published, snaps)
+    return values
+
+
+def control_readings(run, snaps=None) -> dict:
+    """Each compared number of the answers as the control reads it
+    (``snaps``: the reference's answers to reuse, such as the check's)."""
+    from repro.graph.query import query_kind
+    if run.stream is not None:
+        return stale_readings(run, snaps or reference.Snapshots(
+            run.graph, run.stream))
     part = partial_graph(run.graph)
     host = reference.HostGraph(part.n, part.src, part.dst)
     answered = []

@@ -11,12 +11,16 @@ pairs are dropped and the ids are compacted to the non-isolated vertices
 store holds directed rows, so each pair becomes two rows, one in each
 orientation: the first ``m / 2`` rows hold each pair in the orientation
 of its first generated copy, in generation order, and the last ``m / 2``
-the same pairs reversed.
+the same pairs reversed. The generator also returns, for each Kronecker
+label, the compacted id it became (-1 where no kept pair touches it), so
+that an update stream drawn in the same label space (``streamgen``) lands
+on the same vertices.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,10 +32,12 @@ _BIG = np.iinfo(np.int32).max
 @dataclasses.dataclass(frozen=True)
 class Graph:
     """Host copy of a generated graph: ``src``/``dst`` are its directed
-    rows (int32), both orientations of every undirected pair."""
+    rows (int32), both orientations of every undirected pair; ``labels``
+    maps each Kronecker label to its compacted id, or -1."""
     n: int
     src: np.ndarray
     dst: np.ndarray
+    labels: Optional[np.ndarray] = None
 
     @property
     def m(self) -> int:
@@ -90,8 +96,10 @@ def _generate(key, *, scale, edge_factor, a, b, c):
     _, src, dst = jax.lax.sort(
         (order, jnp.where(flipped, hi_c, lo_c),
          jnp.where(flipped, lo_c, hi_c)), num_keys=1)
+    labels_c = jnp.where(present[perm], new_id[perm], -1)
     return {"n": present.sum(dtype=jnp.int32),
-            "pairs": valid.sum(dtype=jnp.int32), "src": src, "dst": dst}
+            "pairs": valid.sum(dtype=jnp.int32), "src": src, "dst": dst,
+            "labels": labels_c}
 
 
 def generate(seed: int, graph: dict) -> Graph:
@@ -109,4 +117,4 @@ def generate(seed: int, graph: dict) -> Graph:
     pairs = int(got["pairs"])
     src, dst = got["src"][:pairs], got["dst"][:pairs]
     return Graph(n=int(got["n"]), src=np.concatenate([src, dst]),
-                 dst=np.concatenate([dst, src]))
+                 dst=np.concatenate([dst, src]), labels=got["labels"])

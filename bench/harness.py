@@ -13,10 +13,16 @@ an edit here.
 A configuration is a deployment of the graph store: an undirected Graph500
 Kronecker graph made on the device from the seed (``graphgen``), loaded in
 one epoch through ``GraphQueryServer.step`` into a WAL-backed
-``ShardedDynamicGraph``. A traffic mix names its readers: closed-loop RPC
-clients, each sending a query drawn from the mix and waiting for its
-answer before sending the next. They talk over ``GraphRPCClient`` to a
-``GraphRPCServer`` in this process, as a deployment's clients would.
+``ShardedDynamicGraph`` served by a ``GraphQueryServer`` (keeping the
+``server.view_keep`` stitched views its configuration sets, if any), and,
+where it has a
+``stream`` section, an update stream over it (``streamgen``). A traffic
+mix names its readers: closed-loop RPC clients, each sending a query
+drawn from the mix and waiting for its answer before sending the next.
+They talk over ``GraphRPCClient`` to a ``GraphRPCServer`` in this
+process, as a deployment's clients would. A mix may also name a writer,
+which hands the stream's epochs to ``GraphQueryServer.step`` on a fixed
+period, open loop.
 """
 from __future__ import annotations
 
@@ -36,12 +42,14 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from bench import checks, graphgen, tracereduce
+from bench import checks, graphgen, streamgen, tracereduce
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 # how long past the close of the window an answer is waited for
 ANSWER_GRACE_S = 60.0
 FRONTIER_KINDS = ("k_hop", "reachability")
+# kinds answered with one call per snapshot, whatever a window holds
+SNAPSHOT_KINDS = ("degree_topk", "pagerank")
 
 
 # ------------------------------------------------------------ definitions
@@ -126,6 +134,19 @@ class Request:
 
 
 @dataclasses.dataclass
+class Epoch:
+    """One epoch as the writer handed it to ``server.step`` (the load is
+    epoch 0)."""
+    epoch: int
+    t_due: float                       # when the schedule handed it over
+    t_call: Optional[float] = None     # None: due, but the window closed
+    t_ret: Optional[float] = None
+    published: bool = False            # latest_version() reached it then
+    in_window: bool = False
+    span_n: Optional[dict] = None      # growth of each span's count over it
+
+
+@dataclasses.dataclass
 class Run:
     """Everything the metric readers and the check read."""
     cell: Cell
@@ -134,7 +155,10 @@ class Run:
     trace: bool
     device: dict
     peaks: dict
+    started: float = 0.0               # process start (wall clock)
     graph: Optional[graphgen.Graph] = None
+    stream: Optional[streamgen.Stream] = None
+    epochs: list = dataclasses.field(default_factory=list)
     setup_s: float = 0.0
     t_open: float = 0.0
     t_close: float = 0.0
@@ -170,8 +194,13 @@ def make_query(entry: dict, n: int, rng: np.random.Generator,
     """One query of a mix entry; vertices are drawn uniformly among the
     graph's ``n`` (non-isolated) vertices, as Graph500 draws BFS roots,
     unless ``source`` is given."""
-    from repro.graph.query import KHop, Reachability
+    from repro.graph.query import (DegreeTopK, KHop, PageRankQuery,
+                                   Reachability)
     kind = entry["kind"]
+    if kind == "degree_topk":
+        return DegreeTopK(int(entry["k"]), direction=entry["direction"])
+    if kind == "pagerank":
+        return PageRankQuery(top_k=int(entry["top_k"]))
     if kind not in FRONTIER_KINDS:
         raise ValueError(f"unknown query kind {kind!r} in a traffic mix")
     a, b = rng.integers(0, n, 2)
@@ -216,22 +245,35 @@ def counting_client(address, timeout_s: float):
 class Deployment:
     """The system under test: store, query server and RPC front."""
 
-    def __init__(self, config: dict, graph: graphgen.Graph, wal_dir: str):
+    def __init__(self, config: dict, graph: graphgen.Graph, wal_dir: str,
+                 stream: Optional[streamgen.Stream] = None):
         from repro.graph.sharded import RoutingPlan, ShardedDynamicGraph
         from repro.launch.rpc import GraphRPCServer
         from repro.launch.serve_graph import GraphQueryServer
         store = config["store"]
         shards = int(store["shards"])
-        # each shard's capacity: the rows the store's own route sends it
-        per_shard = np.bincount(RoutingPlan.initial(shards).assign(
-            graph.dst), minlength=shards)
+        # each shard's capacity: the rows the store's own route sends it,
+        # the stream's adds included, since deleted rows are never freed
+        dst = graph.dst
+        if stream is not None:
+            dst = np.concatenate([dst, stream.all_adds()[1]])
+        per_shard = np.bincount(RoutingPlan.initial(shards).assign(dst),
+                                minlength=shards)
         e_max = int(per_shard.max()) + 1024
         self.graph = graph
+        self.stream = stream
         self.wal_dir = wal_dir
         self.store = ShardedDynamicGraph(
             shards, graph.n, e_max, wal_dir=wal_dir,
             wal_fsync=store["wal_fsync"], parallel_apply=shards)
-        self.server = GraphQueryServer(self.store)
+        # how many stitched views the query server keeps, where the
+        # configuration sets it: each holds every live row on the host
+        server = dict(config.get("server", {}))
+        keep = server.pop("view_keep", None)
+        if server:
+            raise ValueError(f"unknown server settings {sorted(server)}")
+        self.server = GraphQueryServer(
+            self.store, **({} if keep is None else {"view_keep": int(keep)}))
         self.rpc = GraphRPCServer(
             self.server, batch_wait_s=float(config["rpc"]["batch_wait_s"]))
 
@@ -248,6 +290,36 @@ class Deployment:
 
     def stats(self) -> dict:
         return dataclasses.asdict(self.server.stats())
+
+    def batch(self, epoch: int):
+        """The stream's epoch ``epoch`` as one mutation batch."""
+        from repro.core.versioned import Version
+        from repro.graph.dyngraph import MutationBatch
+        (add_src, add_dst), (del_src, del_dst) = (
+            self.stream.adds(epoch), self.stream.deletes(epoch))
+        return MutationBatch(Version(epoch, 0), add_src=add_src,
+                             add_dst=add_dst, del_src=del_src,
+                             del_dst=del_dst)
+
+    def step(self, batch, record: Epoch) -> Epoch:
+        """Hand ``batch`` to ``server.step`` and fill in ``record``: when
+        the step ran, whether the epoch was published when it returned,
+        and how many times each span ran in it."""
+        import jax
+        before = self.stats()
+        record.t_call = time.perf_counter()
+        with jax.profiler.TraceAnnotation("bench.step",
+                                          epoch=batch.version.epoch):
+            self.server.step(batch)
+        record.t_ret = time.perf_counter()
+        latest = self.server.latest_version()
+        record.published = (latest is not None
+                             and latest.epoch >= batch.version.epoch)
+        after = self.stats()
+        if "span_n" in before:
+            record.span_n = {k: v - before["span_n"].get(k, 0)
+                             for k, v in after["span_n"].items()}
+        return record
 
     def close(self) -> None:
         """Stop every thread and close every file of the system."""
@@ -283,26 +355,42 @@ def routed_shards(dep: Deployment, hops: Optional[int]) -> np.ndarray:
 
 
 def warm_up(dep: Deployment, traffic: dict, rng: np.random.Generator,
-            started: float) -> None:
-    """Run each shape the window will use once, so nothing compiles in it.
+            started: float) -> list[Epoch]:
+    """Run each shape the window will use once, so nothing compiles in it,
+    and return the epochs the writer stepped.
 
-    Frontier kinds (k-hop, reachability) are batched per window; the
-    window's size and the edge subset it is routed to set the shapes of
+    The writer steps its ``warmup_epochs`` first, so that the window's
+    epochs ingest, seal, log and publish on a path that has run. Degree
+    top-k and PageRank answer a window with one call per snapshot,
+    whatever the window holds: one window of each mix entry warms them.
+    Frontier kinds (k-hop, reachability) are batched per window, and the
+    window's source count and the rows it is routed to set the shapes of
     their programs. They are warmed at every window size the readers can
     form on a small graph with the same vertex count (the programs that
-    depend on the size alone), and on the deployment at the widths the
-    sweep pads a window to (powers of two, ``graph/compute.py``) from
-    sources whose expansion reads every shard. The routed subset is the
-    full rows of each shard read, padded to a power of two, so subsets of
-    one and of two shards have shapes of their own: one source of each
-    warms them (a window of one is the closed loop's straggler; a window
-    of several sources that all read the same one or two shards is too
-    rare to warm)."""
+    depend on the size alone), and on the deployment from sources whose
+    expansion reads every shard, at each power-of-two source width up to
+    the readers' count. A routed window's rows are its sources' frontier
+    closure padded to a power of two, and the first routed window of a
+    source width compiles the sweep at every width of the snapshot's
+    ladder (``graph/query.py``), so one window of each source width warms
+    them all. The two windows of one source whose expansion reads one or
+    two shards remain from when a window was routed to whole shards; they
+    warm no shape that the ladder has not."""
+    epochs = []
+    for writer in traffic.get("writers", []):
+        for epoch in range(1, int(writer["warmup_epochs"]) + 1):
+            epochs.append(dep.step(dep.batch(epoch),
+                                   Epoch(epoch, time.perf_counter())))
+            note(f"warmed the writer at epoch {epoch}", started)
     server = dep.server
     n = dep.graph.n
     for reader in traffic["readers"]:
         widest = int(reader["clients"])
         for entry in reader["mix"]:
+            if entry["kind"] in SNAPSHOT_KINDS:
+                run_window(server, [make_query(entry, n, rng)])
+                note(f"warmed {entry}", started)
+                continue
             fresh = iter(rng.permutation(n))
             with small_server(dep) as side:
                 for size in range(1, widest + 1):
@@ -332,6 +420,7 @@ def warm_up(dep: Deployment, traffic: dict, rng: np.random.Generator,
                     run_window(server, [make_query(
                         entry, n, rng, source=int(rng.choice(few)))])
                     note(f"warmed {entry} on {count} shard(s)", started)
+    return epochs
 
 
 def run_window(server, queries) -> None:
@@ -417,6 +506,35 @@ def closed_reader(run: Run, address, reader: dict, t_end: float,
             for i in range(int(reader["clients"]))]
 
 
+def writer(run: Run, dep: Deployment, spec: dict, t_open: float,
+           t_end: float, lock: threading.Lock) -> Callable:
+    """The writer's thread: epoch after epoch of the stream, each due a
+    period after the last, open loop (a late step delays the next call,
+    never the schedule). It stops at the window's close; the epochs due
+    by then that it had not handed over are recorded as due."""
+    period = float(spec["period_s"])
+    first = 1 + max(e.epoch for e in run.epochs)
+
+    def loop() -> None:
+        k = 0
+        while t_open + k * period < t_end:
+            record = Epoch(first + k, t_open + k * period, in_window=True)
+            k += 1
+            if time.perf_counter() >= t_end:
+                with lock:
+                    run.epochs.append(record)
+                continue
+            batch = dep.batch(record.epoch)
+            time.sleep(max(0.0, record.t_due - time.perf_counter()))
+            dep.step(batch, record)
+            with lock:
+                run.epochs.append(record)
+            note(f"epoch {record.epoch} stepped in "
+                 f"{record.t_ret - record.t_call:.2f} s, published "
+                 f"{record.published}", run.started)
+    return loop
+
+
 def _catching(target: Callable, errors: list) -> Callable:
     def run() -> None:
         try:
@@ -427,9 +545,9 @@ def _catching(target: Callable, errors: list) -> Callable:
 
 
 def measure(run: Run, dep: Deployment, clock: CompileClock) -> None:
-    """The measured window: every client sends from the open for
-    ``run.seconds``; the window closes when the last answer in flight is
-    in."""
+    """The measured window: every client sends, and the writer steps,
+    from the open for ``run.seconds``; the window closes when the last
+    answer, and the last step, in flight is in."""
     import jax
     lock = threading.Lock()
     run.stats_before = dep.stats()
@@ -440,6 +558,8 @@ def measure(run: Run, dep: Deployment, clock: CompileClock) -> None:
     for i, reader in enumerate(run.cell.traffic["readers"]):
         targets += closed_reader(run, dep.rpc.address, reader, t_end,
                                  run.seed * 16 + i, lock)
+    for spec in run.cell.traffic.get("writers", []):
+        targets.append(writer(run, dep, spec, t_open, t_end, lock))
     errors: list = []
     threads = [threading.Thread(target=_catching(t, errors))
                for t in targets]
@@ -452,7 +572,9 @@ def measure(run: Run, dep: Deployment, clock: CompileClock) -> None:
         raise RuntimeError("a load thread failed") from errors[0]
     run.t_open = t_open
     run.t_close = max([t_open] + [r.t_done for r in run.requests
-                                  if r.t_done])
+                                  if r.t_done]
+                      + [e.t_ret for e in run.epochs
+                         if e.in_window and e.t_ret])
     run.compiles_in_window = clock.count - compiles_before
     run.stats_after = dep.stats()
 
@@ -483,26 +605,58 @@ def enable_cache() -> None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
 
+def check_writers(cell: Cell, seconds: float) -> None:
+    """Refuse a cell whose writer the configuration's stream cannot feed
+    for a run of ``seconds``, or whose epochs the store cannot stamp."""
+    from repro.core.versioned import EPOCH_LIMIT
+    writers = cell.traffic.get("writers", [])
+    stream = cell.config.get("stream")
+    if not writers:
+        return
+    if len(writers) > 1 or stream is None:
+        raise ValueError(f"{cell.name}: a mix takes one writer, over the "
+                         "configuration's stream")
+    spec = writers[0]
+    needed = int(spec["warmup_epochs"]) + int(
+        np.ceil(seconds / float(spec["period_s"])))
+    if int(stream["epochs"]) < needed:
+        raise ValueError(f"{cell.name}: the stream holds {stream['epochs']} "
+                         f"epochs and a run of {seconds} s steps {needed}")
+    if int(stream["epochs"]) >= EPOCH_LIMIT:
+        raise ValueError(f"{cell.name}: {stream['epochs']} epochs pass the "
+                         f"store's {EPOCH_LIMIT - 1}")
+
+
 def execute(cell: Cell, seed: int, seconds: float, trace: bool,
             device: dict, started: float) -> Run:
     """Set up, measure and check one run of ``cell``."""
     import jax
+    check_writers(cell, seconds)
     enable_cache()
     clock = CompileClock()
     jax.monitoring.register_event_duration_secs_listener(clock)
-    run = Run(cell, seed, seconds, trace, device, device_peaks(device["kind"]))
+    run = Run(cell, seed, seconds, trace, device, device_peaks(device["kind"]),
+              started)
     run.graph = graphgen.generate(seed, cell.config["graph"])
     note(f"graph of {run.graph.n} vertices and {run.graph.m} rows", started)
+    if "stream" in cell.config:
+        run.stream = streamgen.generate(seed, cell.config["graph"],
+                                        cell.config["stream"], run.graph)
+        note(f"stream of {run.stream.epochs} epochs, "
+             f"{run.stream.redrawn_share:.4f} of draws redrawn", started)
     scratch = tempfile.mkdtemp(prefix="bench_")
     dep = None
     try:
         dep = Deployment(cell.config, run.graph,
-                         str(pathlib.Path(scratch) / "wal"))
+                         str(pathlib.Path(scratch) / "wal"), run.stream)
+        t_load = time.perf_counter()
         dep.load()
+        run.epochs.append(Epoch(checks.LOAD_EPOCH, t_load, t_load,
+                                time.perf_counter(), published=True))
         note(f"loaded; {dep.stats()['mirrored_vertices']} mirrored "
              "vertices", started)
-        warm_up(dep, cell.traffic, np.random.default_rng([seed, 0]),
-                started)
+        run.epochs += warm_up(dep, cell.traffic,
+                              np.random.default_rng([seed, 0]), started)
         gc.collect()
         note("warmed up", started)
         run.setup_s = time.time() - started
